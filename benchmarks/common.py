@@ -14,9 +14,11 @@ Relative throughputs reproduce the paper's claims; absolute numbers are CPU.
 from __future__ import annotations
 
 import dataclasses
+import os
+import subprocess
 import sys
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -137,6 +139,39 @@ def key_stream(pattern: str, steps: int, batch: int, n_rows: int, seed: int = 0)
             rows = np.concatenate([rows, fill])
         out.append(jnp.asarray(np.sort(rows[:batch]).astype(np.int32)))
     return out
+
+
+def cpu_child_rows(module: str, args: Sequence, n_devices: int,
+                   prefix: str) -> List[Tuple[str, float, str]]:
+    """Run ``python -m module *args`` on ``n_devices`` virtual CPU devices
+    and parse the ``name,us,derived`` rows it prints under ``prefix``.
+
+    The child is pinned to the CPU: this process may already hold the
+    accelerator, and a chip belongs to one process at a time.  The device
+    count must be set before jax is imported, hence the subprocess.  A
+    child that fails, or prints no rows, raises.
+    """
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}",
+        PYTHONPATH=os.path.join(root, "src") + os.pathsep
+        + os.environ.get("PYTHONPATH", ""))
+    cmd = [sys.executable, "-m", module, *map(str, args)]
+    r = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                       timeout=1800, cwd=root)
+    if r.returncode != 0:
+        raise RuntimeError(f"{module} child exited {r.returncode}: "
+                           f"{r.stderr.strip()[-2000:]}")
+    rows = []
+    for line in r.stdout.splitlines():
+        if line.startswith(prefix):
+            name, us, derived = line.split(",", 2)
+            rows.append((name, float(us), derived))
+    if not rows:
+        raise RuntimeError(f"{module} child printed no {prefix}* rows")
+    return rows
 
 
 def emit(rows):

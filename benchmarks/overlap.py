@@ -49,8 +49,6 @@ before jax is imported.
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 import time
 
@@ -58,7 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import ROW_ELEMS, Region, key_stream
+from .common import ROW_ELEMS, Region, cpu_child_rows, key_stream
 
 SHARDED_DEVICES = 8
 # The sharded store protects this many separately-sharded leaves (= vilamb
@@ -213,33 +211,9 @@ def sharded_child(steps: int, n_rows: int, batch: int, period: int) -> None:
 
 
 def _sharded_rows(steps: int, n_rows: int, batch: int, period: int):
-    """Spawn the multi-device child and parse its CSV rows.
-
-    Paths are anchored off ``__file__`` (never the caller's cwd) so the
-    rows survive ``python -m benchmarks.run`` launched from anywhere.
-    """
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={SHARDED_DEVICES}",
-        PYTHONPATH=os.path.join(root, "src") + os.pathsep
-        + os.environ.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.overlap", "--sharded-child",
-           str(steps), str(n_rows), str(batch), str(period)]
-    try:
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=1800, cwd=root)
-    except Exception as e:  # keep the harness running without the rows
-        return [("overlap_sharded/ERROR", 0.0, f"spawn failed: {e}")]
-    if r.returncode != 0:
-        return [("overlap_sharded/ERROR", 0.0,
-                 f"exit {r.returncode}: {r.stderr.strip()[-200:]}")]
-    rows = []
-    for line in r.stdout.splitlines():
-        if line.startswith("overlap_sharded/"):
-            name, us, derived = line.split(",", 2)
-            rows.append((name, float(us), derived))
-    return rows
+    return cpu_child_rows(
+        "benchmarks.overlap", ["--sharded-child", steps, n_rows, batch, period],
+        SHARDED_DEVICES, "overlap_sharded/")
 
 
 def run(steps: int = 240, n_rows: int = 4096, batch: int = 32,

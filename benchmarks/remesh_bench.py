@@ -24,8 +24,6 @@ before jax is imported — same protocol as benchmarks/scrub_bench.py.
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 import time
 
@@ -33,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import ROW_ELEMS, Region, key_stream
+from .common import ROW_ELEMS, Region, cpu_child_rows, key_stream
 
 SHARDED_DEVICES = 8
 ROW_BYTES = ROW_ELEMS * 4
@@ -120,8 +118,7 @@ def sharded_child(steps: int, n_rows: int, batch: int, period: int) -> None:
     jax.block_until_ready(heap)
     during_us = (time.perf_counter() - t0) / max(i, 1) * 1e6
     if status is None or not status.done:
-        print("remesh/migrate_ERROR,0.0,migration did not finish in budget")
-        return
+        raise RuntimeError("migration did not finish in budget")
     moved_bytes = n_rows * ROW_BYTES
     wall_s = during_us * 1e-6 * i
     mb_s = moved_bytes / max(wall_s, 1e-9) / 1e6
@@ -141,28 +138,9 @@ def sharded_child(steps: int, n_rows: int, batch: int, period: int) -> None:
 
 
 def _sharded_rows(steps: int, n_rows: int, batch: int, period: int):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={SHARDED_DEVICES}",
-        PYTHONPATH=os.path.join(root, "src") + os.pathsep
-        + os.environ.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.remesh_bench", "--sharded-child",
-           str(steps), str(n_rows), str(batch), str(period)]
-    try:
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=1800, cwd=root)
-    except Exception as e:  # keep the harness running without the rows
-        return [("remesh/migrate_ERROR", 0.0, f"spawn failed: {e}")]
-    if r.returncode != 0:
-        return [("remesh/migrate_ERROR", 0.0,
-                 f"exit {r.returncode}: {r.stderr.strip()[-200:]}")]
-    rows = []
-    for line in r.stdout.splitlines():
-        if line.startswith("remesh/"):
-            name, us, derived = line.split(",", 2)
-            rows.append((name, float(us), derived))
-    return rows
+    return cpu_child_rows(
+        "benchmarks.remesh_bench", ["--sharded-child", steps, n_rows, batch, period],
+        SHARDED_DEVICES, "remesh/")
 
 
 def run(steps: int = 24, n_rows: int = 2048, batch: int = 32,

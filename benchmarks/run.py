@@ -81,6 +81,8 @@ def main(argv=None) -> None:
                         "the minimum too)")
     args = p.parse_args(argv)
 
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (battery, dirty_cost, fio_patterns, health_bench,
                    insert_throughput, kernel_bench, mttdl_bench, op_latency,
                    overlap, overwrite_scaling, remesh_bench, roofline,
@@ -117,25 +119,21 @@ def main(argv=None) -> None:
             continue
         kw = SMOKE_KW.get(short, {}) if args.smoke else {}
         t0 = time.time()
-        try:
-            # Best-of-N merge by row name: wall rows (us > 0) keep their
-            # fastest repeat, derived-only rows keep the first.
-            merged: dict = {}
-            order: list = []
-            for _ in range(max(args.repeat, 1)):
-                for name, us, derived in mod.run(**kw):
-                    if name not in merged:
-                        merged[name] = (us, derived)
-                        order.append(name)
-                    elif us > 0 and us < merged[name][0]:
-                        merged[name] = (us, derived)
-            rows = [(n, *merged[n]) for n in order]
-            emit(rows)
-            all_rows.extend(rows)
-        except Exception as e:  # keep the harness running
-            print(f"{title},0,ERROR {type(e).__name__}: {e}")
-            all_rows.append((f"{short}/ERROR", 0.0,
-                             f"{type(e).__name__}: {e}"))
+        # Best-of-N merge by row name: wall rows (us > 0) keep their
+        # fastest repeat, derived-only rows keep the first.  A module that
+        # fails fails the run.
+        merged: dict = {}
+        order: list = []
+        for _ in range(max(args.repeat, 1)):
+            for name, us, derived in mod.run(**kw):
+                if name not in merged:
+                    merged[name] = (us, derived)
+                    order.append(name)
+                elif us > 0 and us < merged[name][0]:
+                    merged[name] = (us, derived)
+        rows = [(n, *merged[n]) for n in order]
+        emit(rows)
+        all_rows.extend(rows)
         print(f"# [{title}] {time.time() - t0:.1f}s", file=sys.stderr)
 
     if args.json_path:

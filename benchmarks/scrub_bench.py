@@ -21,8 +21,6 @@ before jax is imported — same protocol as benchmarks/overlap.py.
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 import time
 
@@ -30,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import ROW_ELEMS, Region, key_stream
+from .common import ROW_ELEMS, Region, cpu_child_rows, key_stream
 
 SHARDED_DEVICES = 8
 ROW_BYTES = ROW_ELEMS * 4
@@ -147,8 +145,7 @@ def sharded_child(steps: int, n_rows: int, batch: int, period: int) -> None:
     during_us = (time.perf_counter() - t0) / max(i, 1) * 1e6
     shard_bytes = rows_local * ROW_BYTES
     if rebuild_ticks is None:
-        print("scrub/rebuild_ERROR,0.0,rebuild did not finish in budget")
-        return
+        raise RuntimeError("rebuild did not finish in budget")
     wall_s = during_us * 1e-6 * i
     mb_s = shard_bytes / max(wall_s, 1e-9) / 1e6
     stall = during_us / max(before_us, 1e-9)
@@ -166,28 +163,9 @@ def sharded_child(steps: int, n_rows: int, batch: int, period: int) -> None:
 
 
 def _sharded_rows(steps: int, n_rows: int, batch: int, period: int):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={SHARDED_DEVICES}",
-        PYTHONPATH=os.path.join(root, "src") + os.pathsep
-        + os.environ.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.scrub_bench", "--sharded-child",
-           str(steps), str(n_rows), str(batch), str(period)]
-    try:
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=1800, cwd=root)
-    except Exception as e:  # keep the harness running without the rows
-        return [("scrub/rebuild_ERROR", 0.0, f"spawn failed: {e}")]
-    if r.returncode != 0:
-        return [("scrub/rebuild_ERROR", 0.0,
-                 f"exit {r.returncode}: {r.stderr.strip()[-200:]}")]
-    rows = []
-    for line in r.stdout.splitlines():
-        if line.startswith("scrub/"):
-            name, us, derived = line.split(",", 2)
-            rows.append((name, float(us), derived))
-    return rows
+    return cpu_child_rows(
+        "benchmarks.scrub_bench", ["--sharded-child", steps, n_rows, batch, period],
+        SHARDED_DEVICES, "scrub/")
 
 
 def run(steps: int = 96, n_rows: int = 2048, batch: int = 32,

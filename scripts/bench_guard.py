@@ -14,8 +14,8 @@ reported but never fail.
 
 ``--require PATTERN`` (repeatable, fnmatch) asserts the fresh artifact
 *contains* at least one row matching each pattern — a presence guard for
-rows whose absence would silently drop coverage (e.g. the multi-device
-``overlap/endtoend_*`` legs falling back to their ERROR row).
+rows whose absence would silently drop coverage (e.g. an artifact written
+with ``--only`` that skipped a module).
 
 Exit status 1 on any regression or missing required row, so
 ``scripts/ci.sh`` fails the build.

@@ -8,16 +8,11 @@
 #   CI_FULL_BOTH=1 bash scripts/ci.sh  # run the *entire* suite in both
 #                                      # tick modes (default reruns only
 #                                      # the redundancy-path files)
-#
-# The test suite runs even when pip / the network is unavailable: property
-# tests fall back to the deterministic shim in tests/_hypothesis_fallback.py.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-echo "== [1/6] dependencies (best-effort) =="
-python -m pip install -q hypothesis 2>/dev/null \
-    && echo "hypothesis installed" \
-    || echo "pip/network unavailable - tests use the bundled fallback shim"
+echo "== [1/6] dependencies =="
+python -c "import jax, hypothesis; print('jax', jax.__version__, '/ hypothesis', hypothesis.__version__)"
 
 echo "== [2/6] tier-1 test suite (async_tick=1, the default) =="
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} REPRO_ASYNC_TICK=1 \
@@ -95,9 +90,9 @@ if [ "${SKIP_BENCH:-0}" != "1" ]; then
       --json "${BENCH_JSON:-BENCH_PR10.json}"
   # Regression guard: compare key rows against the prior checked-in
   # artifact; >2x slowdowns fail the build (BENCH_GUARD_TOL overrides).
-  # --require: the multi-device legs must actually produce their rows —
-  # a spawn failure degrades to */ERROR rows, which must fail CI, not
-  # silently drop coverage.  overlap_sharded/overhead_reduction is the
+  # --require: the multi-device legs must actually produce their rows (a
+  # failed child already fails benchmarks.run; this guards the artifact's
+  # coverage).  overlap_sharded/overhead_reduction is the
   # PR10 flagship row (pipelined must beat blocking on the mesh);
   # health/governor_overhead and chaos/recovery_ticks are derived rows
   # (us=0): presence-required, never time-guarded.

@@ -100,32 +100,58 @@ def make_meta(
     )
 
 
+def _packed_rows(meta: BlockMeta) -> int:
+    """Lanes per row of the sub-word packing view: 128 where the padded
+    lanes allow it.  A packing view with a trailing dim of
+    ``elems_per_word`` would be tiled (8, 128) on TPU — a 64x blow-up."""
+    return 128 if meta.padded_lanes % 128 == 0 else 1
+
+
 def to_lanes(x: jax.Array, meta: BlockMeta) -> jax.Array:
-    """Bitcast + pad a leaf into its (n_blocks, lanes_per_block) uint32 view."""
+    """Bitcast + pad a leaf into its (n_blocks, lanes_per_block) uint32 view.
+
+    Sub-word dtypes pack ``elems_per_word`` consecutive elements into one
+    lane, first element in the low bits (a little-endian bitcast), built
+    from strided slices so no array ever has a tiny minor dim.
+    """
     epw = meta.elems_per_word
     flat = x.reshape(-1)
-    pad_elems = meta.n_lanes * epw - flat.shape[0]
+    pad_elems = meta.padded_lanes * epw - flat.shape[0]
     if pad_elems:
         flat = jnp.pad(flat, (0, pad_elems))
     if epw == 1:
         lanes = jax.lax.bitcast_convert_type(flat, jnp.uint32)
     else:
-        lanes = jax.lax.bitcast_convert_type(flat.reshape(-1, epw), jnp.uint32)
-    lane_pad = meta.padded_lanes - lanes.shape[0]
-    if lane_pad:
-        lanes = jnp.pad(lanes, (0, lane_pad))
+        width = 32 // epw
+        u = jax.lax.bitcast_convert_type(flat, jnp.dtype(f"uint{width}"))
+        u = u.reshape(-1, _packed_rows(meta) * epw)
+        lanes = u[:, 0::epw].astype(jnp.uint32)
+        for i in range(1, epw):
+            lanes = lanes | (u[:, i::epw].astype(jnp.uint32)
+                             << jnp.uint32(width * i))
     return lanes.reshape(meta.n_blocks, meta.lanes_per_block)
 
 
 def from_lanes(lanes: jax.Array, meta: BlockMeta) -> jax.Array:
     """Inverse of :func:`to_lanes` (used by parity reconstruction)."""
     epw = meta.elems_per_word
-    flat = lanes.reshape(-1)[: meta.n_lanes]
     dt = jnp.dtype(meta.dtype)
     if epw == 1:
-        out = jax.lax.bitcast_convert_type(flat, dt)
+        out = jax.lax.bitcast_convert_type(lanes.reshape(-1), dt)
     else:
-        out = jax.lax.bitcast_convert_type(flat, dt).reshape(-1)
+        width = 32 // epw
+        ut = jnp.dtype(f"uint{width}")
+        w = lanes.reshape(-1, _packed_rows(meta))
+        packed = None
+        for i in range(epw):
+            part = ((w >> jnp.uint32(width * i))
+                    & jnp.uint32((1 << width) - 1)).astype(ut)
+            # Interior padding interleaves: element i of every lane lands
+            # at column lane * epw + i.
+            part = jax.lax.pad(part, ut.type(0),
+                               ((0, 0, 0), (i, epw - 1 - i, epw - 1)))
+            packed = part if packed is None else packed | part
+        out = jax.lax.bitcast_convert_type(packed, dt).reshape(-1)
     return out[: meta.n_elems].reshape(meta.shape)
 
 

@@ -25,13 +25,12 @@ from typing import Any, Callable, Dict, Mapping, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import bits, blocks, checksum, parity, workqueue
 from .blocks import BlockMeta, DEFAULT_LANES_PER_BLOCK, DEFAULT_STRIPE_DATA_BLOCKS
 from .state import LeafRedundancy, RedundancyState, empty_leaf_red, leaf_red_struct
-
-from repro.common.compat import shard_map
 
 # Dirty-event sentinel: "every block of this leaf was (potentially) written".
 ALL = "__all__"
@@ -45,8 +44,8 @@ class RedundancyConfig:
     scrub_period_steps: int = 64
     lanes_per_block: int = DEFAULT_LANES_PER_BLOCK
     stripe_data_blocks: int = DEFAULT_STRIPE_DATA_BLOCKS
-    use_kernels: bool = False            # Pallas path (interpret on CPU)
-    kernel_interpret: bool = True        # no real TPU in this container
+    use_kernels: bool = False            # Pallas fused-update kernel
+    kernel_interpret: bool = False       # Pallas interpreter (CPU tests)
     # XLA work-queue compaction: per-leaf queue capacity as a fraction of the
     # leaf's stripe count (<= 0 disables; see core/workqueue.py).  Overflow
     # (checked host-side via queue_fits) falls back to the full masked

@@ -97,7 +97,7 @@ class RedundancyPolicy:
     lanes_per_block: int = DEFAULT_LANES_PER_BLOCK
     stripe_data_blocks: int = DEFAULT_STRIPE_DATA_BLOCKS
     use_kernels: bool = False
-    kernel_interpret: bool = True
+    kernel_interpret: bool = False
     # Default XLA work-queue capacity (fraction of a leaf's stripe count);
     # per-group override via LeafPolicy.work_queue_frac.
     work_queue_frac: float = workqueue.DEFAULT_QUEUE_FRAC

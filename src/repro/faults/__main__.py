@@ -348,28 +348,35 @@ def sharded_rebuild_case(seed, steps, mesh, specs) -> int:
     return 0 if ok else 1
 
 
-def sharded_pass(seed: int, steps: int) -> int:
-    """Spawn the sharded battery under 8 forced host devices.
+def _cpu_child(args, label: str) -> int:
+    """Re-exec this module under 8 forced host CPU devices; 1 on failure.
 
-    ``XLA_FLAGS`` must be set before jax is imported, so this re-execs the
-    module rather than re-configuring the already-initialized backend.
+    ``XLA_FLAGS`` must be set before jax is imported, so the module is
+    re-executed rather than re-configuring the initialized backend.  The
+    child is pinned to the CPU: this process may hold the accelerator, and
+    a chip belongs to one process at a time.
     """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     try:
-        r = subprocess.run(
-            [sys.executable, "-m", "repro.faults", "--sharded-child",
-             "--seeds", str(seed), "--steps", str(steps)],
-            env=env, capture_output=True, text=True, timeout=1800)
+        r = subprocess.run([sys.executable, "-m", "repro.faults", *args],
+                           env=env, capture_output=True, text=True,
+                           timeout=1800)
     except Exception as e:   # timeout/OSError: count it, keep the summary
-        print(f"  sharded battery subprocess FAILED ({e!r})")
+        print(f"  {label} subprocess FAILED ({e!r})")
         return 1
     sys.stdout.write(r.stdout)
     if r.returncode != 0:
         sys.stdout.write(r.stderr[-4000:])
-        print(f"  sharded battery subprocess FAILED (exit {r.returncode})")
+        print(f"  {label} subprocess FAILED (exit {r.returncode})")
         return 1
     return 0
+
+
+def sharded_pass(seed: int, steps: int) -> int:
+    """Spawn the sharded battery under 8 forced host devices."""
+    return _cpu_child(["--sharded-child", "--seeds", str(seed),
+                       "--steps", str(steps)], "sharded battery")
 
 
 def chaos_child(seed: int, smoke: bool) -> int:
@@ -384,26 +391,9 @@ def chaos_child(seed: int, smoke: bool) -> int:
 
 def chaos_pass(seed: int, smoke: bool) -> int:
     """Spawn the chaos soak under 8 forced host devices (the shard-loss
-    and remesh storm phases need a mesh; XLA_FLAGS must predate the jax
-    import, so this re-execs the module)."""
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    cmd = [sys.executable, "-m", "repro.faults", "--chaos-child",
-           "--seeds", str(seed)]
-    if smoke:
-        cmd.append("--smoke")
-    try:
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=1800)
-    except Exception as e:
-        print(f"  chaos soak subprocess FAILED ({e!r})")
-        return 1
-    sys.stdout.write(r.stdout)
-    if r.returncode != 0:
-        sys.stdout.write(r.stderr[-4000:])
-        print(f"  chaos soak subprocess FAILED (exit {r.returncode})")
-        return 1
-    return 0
+    and remesh storm phases need a mesh)."""
+    return _cpu_child(["--chaos-child", "--seeds", str(seed)]
+                      + (["--smoke"] if smoke else []), "chaos soak")
 
 
 def main(argv=None) -> int:

@@ -14,7 +14,7 @@ from .flash_attn import flash_attention_bh
                                              "block_q", "block_k"))
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    causal: bool = True, use_pallas: bool = True, interpret: bool = True,
+    causal: bool = True, use_pallas: bool = True, interpret: bool = False,
     block_q: int = 256, block_k: int = 256,
 ) -> jax.Array:
     """q,k,v: (B, S, H, hd) with KV already expanded to H heads."""

@@ -4,19 +4,10 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
+from ..common import striped_rows
 from . import ref
 from .parity import stripe_parity_striped
-
-
-def _striped(lanes: jax.Array, stripe_width: int) -> jax.Array:
-    nb, L = lanes.shape
-    ns = -(-nb // stripe_width)
-    pad = ns * stripe_width - nb
-    if pad:
-        lanes = jnp.pad(lanes, ((0, pad), (0, 0)))
-    return lanes.reshape(ns, stripe_width, L)
 
 
 @functools.partial(jax.jit, static_argnames=("stripe_width", "use_pallas", "interpret"))
@@ -24,9 +15,11 @@ def stripe_parity(
     lanes2d: jax.Array,
     stripe_width: int = 4,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """uint32[n_stripes, L] XOR parity of a (n_blocks, L) lane view."""
     if not use_pallas:
         return ref.stripe_parity(lanes2d, stripe_width)
-    return stripe_parity_striped(_striped(lanes2d, stripe_width), interpret=interpret)
+    par = stripe_parity_striped(striped_rows(lanes2d, stripe_width),
+                                interpret=interpret)
+    return par.reshape(par.shape[0], lanes2d.shape[1])

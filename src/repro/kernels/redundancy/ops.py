@@ -8,18 +8,9 @@ import jax.numpy as jnp
 
 from repro.core.workqueue import compact_stripe_ids
 
-from ..common import xor_reduce
+from ..common import striped_rows, xor_reduce
 from . import ref
 from .redundancy import fused_update_striped
-
-
-def _striped(lanes: jax.Array, stripe_width: int) -> jax.Array:
-    nb, L = lanes.shape
-    ns = -(-nb // stripe_width)
-    pad = ns * stripe_width - nb
-    if pad:
-        lanes = jnp.pad(lanes, ((0, pad), (0, 0)))
-    return lanes.reshape(ns, stripe_width, L)
 
 
 @functools.partial(
@@ -32,7 +23,7 @@ def fused_update(
     stripe_dirty: jax.Array,
     stripe_width: int = 4,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Masked checksum+parity refresh. Semantics == ref.fused_update."""
     if not use_pallas:
@@ -40,7 +31,7 @@ def fused_update(
             lanes2d, old_checksums, old_parity, block_dirty, stripe_dirty,
             stripe_width)
     nb, L = lanes2d.shape
-    striped = _striped(lanes2d, stripe_width)
+    striped = striped_rows(lanes2d, stripe_width)
     ns = striped.shape[0]
     # Compact dirty stripe ids into the work queue (shared helper with the
     # XLA path); pad by repeating the last live id so trailing grid steps
@@ -48,7 +39,10 @@ def fused_update(
     ids, count, _ = compact_stripe_ids(stripe_dirty, ns, pad_repeat_last=True)
     par_raw, cks_part = fused_update_striped(
         striped, ids, count[None], interpret=interpret)
-    cks_new = xor_reduce(cks_part, (2,)).reshape(ns * stripe_width)[:nb]
+    # Fold the 128 lane partials by halving XORs over a (blocks, 128) view:
+    # a generic reduce, or any fold of the (ns, P, 128) array, compiles
+    # ~50x slower for TPU at a 2 GiB region.
+    cks_new = xor_reduce(cks_part.reshape(ns * stripe_width, -1), 1)[:nb]
     cks = jnp.where(block_dirty, cks_new, old_checksums)
-    par = jnp.where(stripe_dirty[:, None], par_raw, old_parity)
+    par = jnp.where(stripe_dirty[:, None], par_raw.reshape(ns, L), old_parity)
     return cks, par

@@ -1,4 +1,6 @@
 import os
+# A CPU tool: 512 virtual host devices stand in for the production mesh.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

@@ -8,20 +8,16 @@ import to build these meshes on CPU.
 from __future__ import annotations
 
 import jax
-
-try:  # JAX >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older JAX: every axis is Auto already
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / small dry-runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_AXIS_KW(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

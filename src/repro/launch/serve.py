@@ -34,6 +34,8 @@ def main(argv=None):
                          "many decode steps regardless of period")
     args = ap.parse_args(argv)
 
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.common import flatten_dict
     from repro.configs import get_arch, get_smoke
     from repro.core import ProtectedStore, RedundancyPolicy

@@ -27,6 +27,33 @@ import jax
 import numpy as np
 
 
+def inject_corruption(trainer, store, state):
+    """SDC demonstration: flip bits in one protected block outside the
+    vulnerability window, then scrub -> parity repair -> re-scrub.
+
+    Returns the flushed state (its own leaves stay intact; the corruption
+    lands in a copy) and the counts ``detected``, ``repaired``,
+    ``unrecoverable`` and ``residual``.
+    """
+    from repro.core import blocks as B
+    from repro.train import protected_leaves
+
+    state = trainer.flush(state)  # make everything clean/covered
+    leaves = protected_leaves(state.params, state.opt)
+    name = sorted(store.protected_metas)[0]
+    meta = store.metas[name]
+    lanes = B.to_lanes(leaves[name], meta)
+    lanes = lanes.at[0, 0].add(np.uint32(0xDEAD))
+    leaves[name] = B.from_lanes(lanes, meta)
+    mm = store.scrub(leaves, state.red)
+    n_bad = int(sum(int(v.sum()) for v in jax.tree.leaves(mm)))
+    repaired, fixed, lostn = store.repair(leaves, state.red, mm)
+    mm2 = store.scrub(repaired, state.red)
+    n_after = int(sum(int(v.sum()) for v in jax.tree.leaves(mm2)))
+    return state, {"detected": n_bad, "repaired": fixed,
+                   "unrecoverable": lostn, "residual": n_after}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -52,14 +79,15 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import get_arch, get_smoke
     from repro.core import ProtectedStore, RedundancyPolicy
-    from repro.core import blocks as B
     from repro.data import SyntheticPipeline
     from repro.models import build_model
     from repro.models.config import ShapeConfig
     from repro.optim import AdamW, warmup_cosine
-    from repro.train import Trainer, protected_leaves, protected_structs
+    from repro.train import Trainer, protected_structs
     from repro.ckpt import CheckpointManager, PreemptionHandler
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
@@ -114,20 +142,10 @@ def main(argv=None):
         # Demonstration: SDC injection -> scrub detect -> parity repair.
         if args.inject_corruption and done >= args.inject_corruption and store:
             args.inject_corruption = 0
-            state = trainer.flush(state)  # make everything clean/covered
-            leaves = protected_leaves(state.params, state.opt)
-            name = sorted(store.protected_metas)[0]
-            meta = store.metas[name]
-            lanes = B.to_lanes(leaves[name], meta)
-            lanes = lanes.at[0, 0].add(np.uint32(0xDEAD))
-            leaves[name] = B.from_lanes(lanes, meta)
-            mm = store.scrub(leaves, state.red)
-            n_bad = int(sum(int(v.sum()) for v in jax.tree.leaves(mm)))
-            repaired, fixed, lostn = store.repair(leaves, state.red, mm)
-            mm2 = store.scrub(repaired, state.red)
-            n_after = int(sum(int(v.sum()) for v in jax.tree.leaves(mm2)))
-            print(f"[vilamb] injected corruption: detected={n_bad} "
-                  f"repaired={fixed} unrecoverable={lostn} residual={n_after}")
+            state, c = inject_corruption(trainer, store, state)
+            print(f"[vilamb] injected corruption: detected={c['detected']} "
+                  f"repaired={c['repaired']} unrecoverable={c['unrecoverable']} "
+                  f"residual={c['residual']}")
 
         if handler.requested:
             state = handler.drain(trainer, state, ckpt)

@@ -54,8 +54,7 @@ def _moe_local(params, x2d, cfg, ep_axis: Optional[str], fsdp_axis: Optional[str
     """Per-device MoE over local tokens (replicated across ep_axis)."""
     T, d = x2d.shape
     E, K = cfg.n_experts, cfg.top_k
-    ep = (jax.lax.axis_size(ep_axis) if hasattr(jax.lax, "axis_size")
-          else jax.lax.psum(1, ep_axis)) if ep_axis else 1
+    ep = jax.lax.axis_size(ep_axis) if ep_axis else 1
     E_loc = E // ep
     e_off = jax.lax.axis_index(ep_axis) * E_loc if ep_axis else 0
     cap = max(1, min(T * K, int(math.ceil(T * K / E * cfg.capacity_factor))))

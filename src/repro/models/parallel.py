@@ -11,9 +11,8 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.common.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)

@@ -51,9 +51,9 @@ from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.common.compat import shard_map
 from repro.core import checksum
 from repro.core.engine import RedundancyEngine, _local_shape
 from repro.core.blocks import make_meta

@@ -170,6 +170,10 @@ class ScrubPatroller:
         self.ticks = 0
         self.blocks_scanned = 0            # local probe positions covered
         self.starved_ticks = 0             # consecutive ticks with no probe
+        # Probe resolutions: adopted once ``is_ready`` said so, vs force-
+        # fetched after PROBE_FORCE_TICKS not-ready attempts.
+        self.probes_ready = 0
+        self.probes_forced = 0
         self.detections: collections.deque = collections.deque(
             maxlen=OBSERVABILITY_CAP)
         self.latencies: collections.deque = collections.deque(
@@ -416,6 +420,9 @@ class ScrubPatroller:
             # fetch instead of trusting a readiness notification that may
             # never arrive — see PROBE_FORCE_TICKS.
             np.asarray(mism_d), np.asarray(clean_d)
+            self.probes_forced += 1
+        else:
+            self.probes_ready += 1
         self._probe_stuck = 0
         self._probe = None
         inval, self._probe_inval = self._probe_inval, None

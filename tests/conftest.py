@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import HealthCheck, settings as hp_settings
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -23,19 +24,15 @@ SEED = int(os.environ.get("REPRO_TEST_SEED", "0"))
 random.seed(SEED)
 np.random.seed(SEED)
 
-try:  # real hypothesis: pin a derandomized profile so CI runs are replayable
-    from hypothesis import HealthCheck, settings as hp_settings
-
-    hp_settings.register_profile(
-        "repro",
-        derandomize=True,
-        deadline=None,
-        suppress_health_check=list(HealthCheck),
-        print_blob=True,
-    )
-    hp_settings.load_profile("repro")
-except ImportError:  # the bundled fallback shim is deterministic already
-    pass
+# Pin a derandomized hypothesis profile so CI runs are replayable.
+hp_settings.register_profile(
+    "repro",
+    derandomize=True,
+    deadline=None,
+    suppress_health_check=list(HealthCheck),
+    print_blob=True,
+)
+hp_settings.load_profile("repro")
 
 
 def pytest_report_header(config):

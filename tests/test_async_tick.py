@@ -8,10 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core.store as store_mod
 from repro.core import ALL, ProtectedStore, RedundancyPolicy, bits

@@ -1,10 +1,7 @@
 """Packed dirty-bitvector properties (paper §3.2 metadata)."""
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bits
 

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ALL, RedundancyConfig, RedundancyEngine
 from repro.core import bits, blocks as B
@@ -21,7 +18,8 @@ def _mk(seed=0, use_kernels=False):
         "w": jax.random.normal(jax.random.PRNGKey(seed), (24, 200), jnp.float32),
         "e": jax.random.normal(jax.random.PRNGKey(seed + 1), (16, 64), jnp.bfloat16),
     }
-    cfg = dataclasses.replace(CFG, use_kernels=use_kernels)
+    cfg = dataclasses.replace(CFG, use_kernels=use_kernels,
+                              kernel_interpret=use_kernels)
     eng = RedundancyEngine(
         {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in leaves.items()}, cfg)
     return eng, leaves
