@@ -85,8 +85,8 @@ def main(argv=None) -> None:
     use_compile_cache()
     from . import (battery, dirty_cost, fio_patterns, health_bench,
                    insert_throughput, kernel_bench, mttdl_bench, op_latency,
-                   overlap, overwrite_scaling, remesh_bench, roofline,
-                   scrub_bench, ycsb)
+                   overlap, overwrite_scaling, remesh_bench, scrub_bench,
+                   ycsb)
     from .common import emit
 
     modules = [
@@ -103,7 +103,6 @@ def main(argv=None) -> None:
         ("elastic remesh + degraded reads", remesh_bench),
         ("health governor + breaker recovery", health_bench),
         ("kernel fusion", kernel_bench),
-        ("roofline", roofline),
     ]
     selected = {s.strip() for s in args.only.split(",") if s.strip()}
     known = {mod.__name__.rsplit(".", 1)[-1] for _, mod in modules}

@@ -201,7 +201,8 @@ def region_phase(rows: int = REGION_ROWS, steps: int = 200, seed: int = 0,
             heap = rep.repaired["heap"]
             repaired = True
             break
-    detected = [d for d in pat.detections if d.block == block]
+    # The one registered injection's latency lands once the patrol finds it.
+    detected = list(pat.latencies)
     check(detected and repaired, f"patrol detected+repaired block {block}")
     check(same_bits(heap, mirror), "heap equals the mirror after repair")
     check(store.scrub_check({"heap": heap}, red) == 0,
@@ -241,9 +242,9 @@ def region_phase(rows: int = REGION_ROWS, steps: int = 200, seed: int = 0,
         setup_s=f"{setup_s:.2f}", first_calls_s=f"{first_s:.2f}",
         steady_ms_per_step=f"{steady_s / (steps - len(BATCHES)) * 1e3:.3f}",
         kernel_update_first_s=f"{kernel_first_s:.2f}",
-        patrol_probes_ready=pat.probes_ready,
-        patrol_probes_forced=pat.probes_forced,
-        detect_latency_steps=detected[0].latency_steps,
+        patrol_probes_ready=store.counters["patrol.probes_ready"],
+        patrol_probes_forced=store.counters["patrol.probes_forced"],
+        detect_latency_steps=detected[0],
         peak_gib=peak_gib())
     report("region", **facts)
     return facts
@@ -507,8 +508,9 @@ def sharded_phase(rows: int = 1 << 18, steps: int = 60, seed: int = 0,
         setup_s=f"{setup_s:.2f}",
         traffic_ms_per_step=f"{traffic_s / steps * 1e3:.3f}",
         rebuild_ticks=rebuild_ticks, rebuild_s=f"{rebuild_s:.2f}",
-        patrol_probes_ready=pat.probes_ready,
-        patrol_probes_forced=pat.probes_forced, peak_gib=peak_gib())
+        patrol_probes_ready=store.counters["patrol.probes_ready"],
+        patrol_probes_forced=store.counters["patrol.probes_forced"],
+        peak_gib=peak_gib())
     report("sharded", **facts)
     return facts
 

@@ -216,33 +216,51 @@ class RedundancyEngine:
     def queue_fits(self, red: RedundancyState) -> bool:
         """Host-side overflow check: do all live dirty stripes fit the queues?
 
-        One tiny jitted popcount pass over the bitvectors (O(n_blocks) bits,
-        no data read) and a single bool transfer — the cost that buys
-        dispatching the ∝-dirty queued program instead of the full one.
-        Under a mesh the per-shard dirty-stripe counts are each checked
-        against the shard-local capacity (the queues are per shard); this
-        exact check is the blocking path's — the overlap pipeline computes
-        the same predicate inside the dispatched program instead.
+        The fit half of :meth:`queue_check`; False where no leaf has a
+        queue.
         """
-        if not self.has_queue:
-            return False
+        return self.has_queue and self.queue_check(red)[0]
+
+    def queue_check(self, red: RedundancyState) -> Tuple[bool, int]:
+        """``(fits, dirty stripes)`` of the live view, in one transfer.
+
+        One tiny jitted popcount pass over the bitvectors (O(n_blocks) bits,
+        no data read) — the cost that buys dispatching the ∝-dirty queued
+        program instead of the full one.  Under a mesh the per-shard
+        dirty-stripe counts are each checked against the shard-local
+        capacity (the queues are per shard); ``fits`` is False where no
+        leaf has a queue.  This exact check is the blocking path's — the
+        overlap pipeline computes the same predicate and count inside the
+        dispatched program instead.  The stripe count is what the pass
+        about to run covers (Algorithm 1's work).
+        """
         if self._queue_fits_jit is None:
             def fits(red_l):
-                oks = []
+                oks, total = [], jnp.int32(0)
                 for name, meta in self.metas.items():
-                    cap = self._queue_caps[name]
-                    if not cap:
-                        continue
                     r = red_l[name]
                     k = self.shard_factor(name)
                     bd = bits.unpack_rows(jnp.bitwise_or(r.dirty, r.shadow),
                                           k, meta.n_blocks)
-                    oks.append(jnp.all(jax.vmap(
-                        lambda m: workqueue.stripe_fits(
-                            self._stripe_dirty(meta, m), cap))(bd)))
-                return jnp.all(jnp.stack(oks))
+                    counts = jax.vmap(lambda m: workqueue.stripe_dirty_count(
+                        self._stripe_dirty(meta, m)))(bd)
+                    total = total + jnp.sum(counts, dtype=jnp.int32)
+                    cap = self._queue_caps[name]
+                    if cap:
+                        oks.append(jnp.all(counts <= cap))
+                ok = jnp.all(jnp.stack(oks)) if oks else jnp.asarray(False)
+                return ok, total
             self._queue_fits_jit = jax.jit(fits)
-        return bool(self._queue_fits_jit(red))
+        ok, total = jax.device_get(self._queue_fits_jit(red))
+        return bool(ok), int(total)
+
+    @property
+    def alg1_stripe_bytes(self) -> int:
+        """Algorithm 1's bytes for one dirty stripe: its P data blocks read,
+        its parity block and P checksums written."""
+        block = self.config.lanes_per_block * 4
+        p = self.config.stripe_data_blocks
+        return p * block + block + p * 4
 
     def _update_leaf(self, name: str, meta: BlockMeta, lanes,
                      old: LeafRedundancy, bdirty, sdirty, queued: bool):
@@ -357,14 +375,16 @@ class RedundancyEngine:
         Lines 2-4: snapshot ``dirty | shadow`` (include leftover shadow
         from a crash); lines 7-18 + 22: masked checksum + parity recompute
         with the meta-checksum refreshed incrementally on the work-queue
-        path.  Returns ``({name: (cks, par, meta_ck, snapshot)}, fits)``
-        — the blocking and overlap entry points differ only in how they
-        fold these into dirty/shadow outputs.  ``fits`` (the device-side
-        queue-fit predicate over every queued leaf) is only evaluated when
-        requested.
+        path.  Returns ``({name: (cks, par, meta_ck, snapshot)}, fits,
+        stripes)`` — the blocking and overlap entry points differ only in
+        how they fold these into dirty/shadow outputs.  ``fits`` (the
+        device-side queue-fit predicate over every queued leaf) and
+        ``stripes`` (the dirty stripes this pass covers, int32) are only
+        evaluated when requested.
         """
         parts: Dict[str, Tuple] = {}
         fits = []
+        stripes = jnp.int32(0)
         for name, meta in self.metas.items():
             r = red_l[name]
             snapshot = jnp.bitwise_or(r.dirty, r.shadow)
@@ -373,17 +393,20 @@ class RedundancyEngine:
             cap = self._queue_caps[name]
             if want_fits and cap:
                 fits.append(workqueue.stripe_fits(sdirty, cap))
+            if want_fits:
+                stripes = stripes + workqueue.stripe_dirty_count(sdirty)
             lanes = blocks.to_lanes(ls[name], meta)
             cks, par, meta_ck = self._update_leaf(
                 name, meta, lanes, r, bdirty, sdirty, queued)
             parts[name] = (cks, par, meta_ck, snapshot)
         fits_all = jnp.all(jnp.stack(fits)) if fits else jnp.asarray(True)
-        return parts, fits_all
+        return parts, fits_all, stripes
 
     def _alg1(self, leaves, red: RedundancyState, queued: bool
               ) -> RedundancyState:
         def local(ls, red_l):
-            parts, _ = self._alg1_parts(ls, red_l, queued, want_fits=False)
+            parts, _, _ = self._alg1_parts(ls, red_l, queued,
+                                           want_fits=False)
             out = {}
             for name, (cks, par, meta_ck, snapshot) in parts.items():
                 # Lines 19-20: in the paper a fence orders "redundancy
@@ -434,12 +457,13 @@ class RedundancyEngine:
     def redundancy_step_async(
         self, leaves: Mapping[str, jax.Array], red: RedundancyState,
         queued: bool = False,
-    ) -> Tuple[RedundancyState, jax.Array]:
+    ) -> Tuple[RedundancyState, jax.Array, jax.Array]:
         """Algorithm 1 restructured for sync-free overlapped dispatch.
 
         Same snapshot-merge and per-leaf math as :meth:`redundancy_step` /
         :meth:`redundancy_step_queued` — one donated in-place program — but
-        returning ``(red_out, fits)`` so no host check guards adoption:
+        returning ``(red_out, fits, stripes)`` so no host check guards
+        adoption:
 
         * ``fits`` is the device-computed queue-fit predicate
           (``queue_fits`` without the host round trip); the dispatcher
@@ -456,13 +480,16 @@ class RedundancyEngine:
           bitmap the foreground's next ``on_write`` marks into.
           :meth:`redundancy_step_queued`'s "never unguarded" contract is
           thus discharged on device.
+        * ``stripes`` is the int32 count of dirty stripes in the snapshot
+          (the work this pass covers when it does not overflow), fetched
+          with ``fits`` in the same transfer.
 
         Under a mesh the whole body runs per shard inside ``shard_map``
         (zero collectives): each shard compacts its own queue, and ``fits``
         is the **per-shard** flag array (global shape ``(n_devices,)``,
-        sharded over every mesh axis).  The overflow select is per shard
-        too — only the shards whose local queue overflowed keep their
-        snapshot marked.  Dispatchers never fold the flags on device: the
+        sharded over every mesh axis), as is ``stripes``.  The overflow
+        select is per shard too — only the shards whose local queue
+        overflowed keep their snapshot marked.  Dispatchers never fold the flags on device: the
         store stacks them into its batched fits vector and AND-folds the
         fetched row on the host at resolution
         (``repro.core.workqueue.fold_fits_host``), so this program — and
@@ -470,8 +497,8 @@ class RedundancyEngine:
         collective-free.
         """
         def local(ls, red_l):
-            parts, fits_all = self._alg1_parts(ls, red_l, queued,
-                                               want_fits=True)
+            parts, fits_all, stripes = self._alg1_parts(ls, red_l, queued,
+                                                        want_fits=True)
             overflowed = (jnp.logical_not(fits_all) if queued
                           else jnp.asarray(False))
             out: RedundancyState = {}
@@ -485,7 +512,8 @@ class RedundancyEngine:
                 )
             if self.mesh is not None:
                 fits_all = fits_all.reshape((1,))
-            return out, fits_all
+                stripes = stripes.reshape((1,))
+            return out, fits_all, stripes
 
         if self.mesh is None:
             return local(dict(leaves), red)
@@ -494,7 +522,8 @@ class RedundancyEngine:
             local, mesh=self.mesh,
             in_specs=(self._leaf_specs_dict(),
                       {n: self.red_spec(n) for n in self.metas}),
-            out_specs=({n: self.red_spec(n) for n in self.metas}, P(axes)),
+            out_specs=({n: self.red_spec(n) for n in self.metas}, P(axes),
+                       P(axes)),
             check_vma=False,
         )
         return fn(dict(leaves), red)

@@ -20,6 +20,7 @@ compiles down to one :class:`~repro.core.engine.RedundancyEngine`.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import fnmatch
 import queue
@@ -37,6 +38,7 @@ from repro.common import flatten_dict
 
 from . import bits
 from . import policy as policy_mod
+from . import trace
 from . import workqueue
 from .blocks import (DEFAULT_LANES_PER_BLOCK, DEFAULT_STRIPE_DATA_BLOCKS,
                      BlockMeta, make_meta)
@@ -348,6 +350,10 @@ class _Pending:
     launched: Optional[threading.Event] = None
     fits_index: int = 0
     fits_host: Optional[bool] = None
+    # The batch's stacked dirty-stripe counts (same layout as ``fits``)
+    # and this group's host sum, published with ``fits_host``.
+    stripes: Any = None
+    stripes_host: Optional[int] = None
     error: Optional[BaseException] = None
     # Health-governor bookkeeping: wall-clock dispatch timestamp (wedged-
     # dispatch detection) and the group's freshness clocks as they stood
@@ -395,6 +401,25 @@ def _fits_host_pending(p: "_Pending") -> bool:
         return bool(p.fits_host)
     arr = np.asarray(p.fits)
     return workqueue.fold_fits_host(arr[p.fits_index] if arr.ndim else arr)
+
+
+def _stripes_host_pending(p: "_Pending") -> int:
+    """This group's dirty-stripe count out of the batch's stacked counts
+    (summed over shards): ``stripes_host`` once published, else a host
+    memory read of the landed copy."""
+    if p.stripes_host is not None:
+        return p.stripes_host
+    arr = np.asarray(p.stripes)
+    return int((arr[p.fits_index] if arr.ndim else arr).sum())
+
+
+def _publish(pendings, fits_host, stripes_host) -> None:
+    """Fold a batch's fetched fits and stripe counts into its pendings."""
+    for i, p in enumerate(pendings):
+        f = fits_host[i] if fits_host.ndim else fits_host
+        n = stripes_host[i] if stripes_host.ndim else stripes_host
+        p.fits_host = workqueue.fold_fits_host(f)
+        p.stripes_host = int(np.sum(n))
 
 
 class _Dispatcher:
@@ -495,6 +520,14 @@ class ProtectedStore:
         # points for crash-consistency replay.  Empty list = zero overhead
         # on every hot path (a single truthiness check).
         self._phase_hooks: List[Callable[[str, Dict[str, Any]], None]] = []
+        # Always-on host counters (repro.core.trace): Algorithm-1 passes and
+        # the dirty stripes they covered (counted on the device, added at
+        # resolution), patrol probes, and ``wait.<site>.{n,s,max_ms}`` for
+        # every place the tick thread blocks.
+        self.counters: Dict[str, float] = dict.fromkeys((
+            "update.passes_queued", "update.passes_full", "update.overflowed",
+            "update.stripes", "update.alg1_bytes", "patrol.probes_ready",
+            "patrol.probes_forced", "patrol.blocks_scanned"), 0)
 
     # -------------------------------------------------------------- phase hooks
     def add_phase_hook(self, fn: Callable[[str, Dict[str, Any]], None]) -> None:
@@ -785,7 +818,7 @@ class ProtectedStore:
         Variants: ``full`` / ``queued`` — the blocking programs (input red
         donated in place; used by ``flush`` and the blocking tick);
         ``async_full`` / ``async_queued`` — the overlap programs
-        ``(leaves, red) -> (red, fits)``.  The overlap programs donate
+        ``(leaves, red) -> (red, fits, stripes)``.  The overlap programs donate
         **nothing**: on this backend a donated dispatch blocks the host
         until its donated inputs are defined, so in-place updates would
         re-serialize the very pipeline the overlap exists to free.  The
@@ -822,28 +855,31 @@ class ProtectedStore:
         one stacked ``(n_groups,)`` vector (``(n_groups, n_devices)`` under
         a mesh — pinned to per-device columns so the program still lowers
         collective-free; the AND-fold over shards happens on the host at
-        resolution, where the row is already fetched memory).
+        resolution, where the row is already fetched memory).  The groups'
+        dirty-stripe counts come back beside it, an int32 vector of the
+        same layout.
         """
         engines = [self.groups[l].engine for l in labels]
         qs = [v == "async_queued" for v in variants]
         mesh = engines[0].mesh
 
         def many(subs, reds):
-            outs, fits = [], []
+            outs, fits, stripes = [], [], []
             for eng, q, sub, rd in zip(engines, qs, subs, reds):
-                o, f = eng.redundancy_step_async(sub, rd, queued=q)
+                o, f, n = eng.redundancy_step_async(sub, rd, queued=q)
                 outs.append(o)
                 fits.append(f)
-            stacked = jnp.stack(fits)
+                stripes.append(n)
+            stacked, counts = jnp.stack(fits), jnp.stack(stripes)
             if mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 # Per-shard flag columns stay device-local: each device
                 # holds its own column of every group's row — stacking is
                 # a local concat, never a collective.
-                stacked = jax.lax.with_sharding_constraint(
-                    stacked,
-                    NamedSharding(mesh, P(None, tuple(mesh.axis_names))))
-            return tuple(outs), stacked
+                cols = NamedSharding(mesh, P(None, tuple(mesh.axis_names)))
+                stacked = jax.lax.with_sharding_constraint(stacked, cols)
+                counts = jax.lax.with_sharding_constraint(counts, cols)
+            return tuple(outs), stacked, counts
 
         return jax.jit(many)
 
@@ -949,11 +985,30 @@ class ProtectedStore:
         exact, host-side ``queue_fits`` round trip (per-shard counts under
         a mesh) — full recompute otherwise; bitwise-identical either way.
         The exact fit answer doubles as a free speculation seed for later
-        overlapped dispatches."""
-        queued = g.engine.has_queue and g.engine.queue_fits(red_sub)
+        overlapped dispatches, and its dirty-stripe count (same transfer)
+        feeds the pass counters.  A group without queues takes the round
+        trip too, for that count alone."""
+        with trace.waited(self.counters, "queue_fits"):
+            fits, stripes = g.engine.queue_check(red_sub)
+        queued = g.engine.has_queue and fits
         g.predicted_fits = queued or not g.engine.has_queue
+        self._count_pass(g, queued, stripes)
         return self._update_fn(g.label, "queued" if queued else "full")(
             sub, red_sub)
+
+    def _count_pass(self, g: _Group, queued: bool,
+                    stripes: Optional[int]) -> None:
+        """Count one Algorithm-1 pass; ``stripes`` None = overflowed (its
+        work is redone by the full fallback, which counts itself)."""
+        c = self.counters
+        trace.add(c, "update.passes_queued" if queued
+                  else "update.passes_full")
+        if stripes is None:
+            trace.add(c, "update.overflowed")
+            return
+        trace.add(c, "update.stripes", stripes)
+        trace.add(c, "update.alg1_bytes",
+                  stripes * g.engine.alg1_stripe_bytes)
 
     def _swap_fn(self, label: str):
         """One-dispatch epoch swap for the live view: per leaf, the epoch-A
@@ -1092,7 +1147,8 @@ class ProtectedStore:
         # thread was measured and rejected: a donating caller (train step,
         # decode step) deletes the captured buffers before the thread gets
         # to shard them.
-        outs, fits = self._update_many_fn(labels, variants)(subs, red_subs)
+        outs, fits, stripes = self._update_many_fn(labels, variants)(
+            subs, red_subs)
         ev = threading.Event() if self.policy.dispatcher_thread else None
         pendings = []
         for i, (g, queued, prev_step, prev_time) in enumerate(items):
@@ -1103,22 +1159,22 @@ class ProtectedStore:
             # wedged device counts as wedged from the moment the
             # foreground handed it off.
             p = _Pending(red=outs[i], fits=fits, queued=queued, step=step,
-                         launched=ev, fits_index=i,
+                         launched=ev, fits_index=i, stripes=stripes,
                          prev_step=prev_step, prev_time=prev_time)
             g.pending = p
             pendings.append(p)
 
         if ev is not None:
             # Off-thread resolver: the dedicated thread rides out device
-            # execution (np.asarray blocks *it*, not the tick) and
-            # publishes the folded per-group fit bits; ``_resolve`` then
-            # only reads plain Python bools.
-            def resolve_job(fits=fits, pendings=pendings, ev=ev):
+            # execution (the fetch blocks *it*, not the tick) and
+            # publishes the folded per-group fit bits and stripe counts;
+            # ``_resolve`` then only reads plain Python values.
+            def resolve_job(fits=fits, stripes=stripes, pendings=pendings,
+                            ev=ev):
                 try:
-                    host = np.asarray(fits)
-                    for i, p in enumerate(pendings):
-                        p.fits_host = workqueue.fold_fits_host(
-                            host[i] if host.ndim else host)
+                    with trace.span("resolver.fetch"):
+                        host = jax.device_get((fits, stripes))
+                    _publish(pendings, *map(np.asarray, host))
                 except BaseException as e:   # surfaces at resolution
                     for p in pendings:
                         p.error = e
@@ -1128,16 +1184,14 @@ class ProtectedStore:
             self._submit(resolve_job)
         elif hasattr(fits, "copy_to_host_async"):
             # Inline mode (PR3..PR8 behavior): start the non-blocking
-            # device->host copy now; resolution folds the landed row.
+            # device->host copies now; resolution folds the landed rows.
             fits.copy_to_host_async()
+            stripes.copy_to_host_async()
         else:
             # Backend without a non-blocking device->host copy: fetch
             # HERE, at dispatch time — the resolve-side read must stay a
             # host memory read, never a synchronous round trip.
-            host = np.asarray(fits)
-            for i, p in enumerate(pendings):
-                p.fits_host = workqueue.fold_fits_host(
-                    host[i] if host.ndim else host)
+            _publish(pendings, np.asarray(fits), np.asarray(stripes))
         view: Dict[str, LeafRedundancy] = {}
         for (g, *_), (snaps, fresh), rs in zip(items, swaps, red_subs):
             view.update({n: dataclasses.replace(
@@ -1172,17 +1226,22 @@ class ProtectedStore:
             return red_sub, False, 0
         if not wait and not _pending_ready(p):
             return None, False, 0
-        if p.launched is not None:
-            p.launched.wait()            # join: no-op unless wait forced it
-        if p.error is not None:
-            g.pending = None
-            raise p.error
-        fits = _fits_host_pending(p)
+        with (trace.waited(self.counters, "resolve") if wait
+              else contextlib.nullcontext()):
+            if p.launched is not None:
+                p.launched.wait()        # join: no-op unless wait forced it
+            if p.error is not None:
+                g.pending = None
+                raise p.error
+            fits = _fits_host_pending(p)
+            overflowed = p.queued and not fits
+            self._count_pass(g, p.queued, None if overflowed
+                             else _stripes_host_pending(p))
         g.predicted_fits = fits
         out = {n: dataclasses.replace(p.red[n], dirty=red_sub[n].dirty)
                for n in g.names}
         g.pending = None
-        return out, (p.queued and not fits), p.coalesced
+        return out, overflowed, p.coalesced
 
     def _drain_background(self, leaves: Dict[str, Any], out: Dict[str, Any],
                           step: Optional[int] = None) -> Dict[str, Any]:
@@ -1250,6 +1309,11 @@ class ProtectedStore:
         signal) — the ``dispatcher_join`` crash phase fires per joined
         group.
         """
+        with trace.span("settle"):
+            return self._settle(red, leaves, step)
+
+    def _settle(self, red: RedundancyState, leaves, step: Optional[int]
+                ) -> RedundancyState:
         out = dict(red)
         if leaves is not None:
             leaves = self._drain_background(dict(leaves), out, step=step)
@@ -1264,20 +1328,25 @@ class ProtectedStore:
                 g, {n: out[n] for n in g.names}, wait=True)
             out.update(red_sub)
             if overflowed and leaves is not None:
-                # Full-recompute repair through the *non-donating* overlap
-                # program: settle also backs read-only paths (scrub), whose
-                # callers keep using their own red — the donating blocking
-                # program would invalidate it.  Bitwise-identical to the
-                # blocking full program (queued=False never overflows, so
-                # its dirty/shadow outputs are zeros too).
-                repaired, fits = self._update_fn(g.label, "async_full")(
-                    {n: leaves[n] for n in g.names},
-                    {n: out[n] for n in g.names})
-                g.predicted_fits = _fits_host(fits)
-                out.update(repaired)
+                self._full_fallback(g, leaves, out)
         if self._phase_hooks:
             self._phase("settle", red=dict(out))
         return out
+
+    def _full_fallback(self, g: _Group, leaves, out: Dict[str, Any]) -> None:
+        """Full-recompute repair of an overflowed speculative pass, adopted
+        into ``out`` now, through the *non-donating* overlap program:
+        settle also backs read-only paths (scrub), whose callers keep using
+        their own red — the donating blocking program would invalidate it.
+        Bitwise-identical to the blocking full program (queued=False never
+        overflows, so its dirty/shadow outputs are zeros too)."""
+        repaired, fits, stripes = self._update_fn(g.label, "async_full")(
+            {n: leaves[n] for n in g.names}, {n: out[n] for n in g.names})
+        with trace.waited(self.counters, "fallback"):
+            fits, stripes = jax.device_get((fits, stripes))
+        g.predicted_fits = _fits_host(fits)
+        self._count_pass(g, False, int(np.sum(stripes)))
+        out.update(repaired)
 
     def _scrub_fn(self, label: str):
         fn = self._jit_scrub.get(label)
@@ -1325,6 +1394,12 @@ class ProtectedStore:
         only live lineage (the blocking path donates the Algorithm-1
         input; the overlapped path tracks the epoch buffers through it).
         """
+        with trace.span("tick"):
+            return self._tick(leaves, red, step, step_time, scrub_period)
+
+    def _tick(self, leaves, red: RedundancyState, step: int,
+              step_time: Optional[float], scrub_period: Optional[int]
+              ) -> Tuple[RedundancyState, TickReport]:
         step = int(step)
         if step_time is not None:
             self._governor.observe(step_time)
@@ -1362,117 +1437,128 @@ class ProtectedStore:
         # migrator recomputes redundancy from current data window by
         # window — a due tick dispatched against the old geometry would
         # race the migration for no benefit.
-        for g in (() if self._remesh is not None else self._protected()):
-            lp = g.policy
-            if step < g.last_update_step:
-                # The step counter restarted (new serve wave / fresh run on a
-                # long-lived store): rebase so deadlines keep their meaning.
-                g.last_update_step = 0
-            sp = scrub_period if scrub_period is not None else lp.scrub_period_steps
-            scrub_due = bool(sp and policy_mod.should_scrub(step, sp))
-            if lp.mode == "vilamb":
-                margin = sync_esc = retry = False
-                if hg is not None:
-                    # Escalation-ladder rung 1: a wedged in-flight update is
-                    # abandoned (freshness clocks roll back to pre-dispatch)
-                    # and re-dispatched below after a bounded backoff.  The
-                    # retry flag forces the dispatch this tick: ``due`` is
-                    # step-aligned, so waiting for the next period boundary
-                    # would let the breaker cool down between retries.
-                    retry = hg.check_pending(g)
-                    sync_esc = hg.is_sync_escalated(g.label)
-                    margin = hg.within_margin(g, step, now)
-                eff = min(lp.period_steps * self._governor.scale,
-                          self.policy.period_cap)
-                due = policy_mod.should_update(step, eff)
-                overdue = (
-                    (lp.max_vulnerable_steps > 0
-                     and step - g.last_update_step >= lp.max_vulnerable_steps)
-                    or (lp.max_vulnerable_seconds > 0
-                        and now - g.last_update_time >= lp.max_vulnerable_seconds))
-                if self._async_group(g) and not sync_esc:
-                    # Overlap pipeline: resolve lazily (blocking only when a
-                    # deadline or a scrub forces settled state), then keep the
-                    # pipeline primed with at most one in-flight update.
-                    had_pending = g.pending is not None
-                    # Rung 2: within the governor's deadline margin the tick
-                    # stops speculating — resolve blocking and re-dispatch,
-                    # meeting the deadline early instead of missing it.
-                    forced = overdue or scrub_due or margin
-                    if had_pending and forced and self._phase_hooks:
-                        # The crash point right before the tick joins the
-                        # dispatcher (launch, then fit signal).
-                        self._phase("dispatcher_join", red=dict(out),
-                                    group=g.label, step=step)
-                    res, ovf, deferred = self._resolve(
-                        g, {n: out[n] for n in g.names}, wait=forced)
-                    if res is None:
-                        # Still in flight: fold this due tick into it.  The
-                        # deadline clock keeps running, so a wedged device
-                        # eventually forces a blocking resolve via overdue.
-                        if due:
-                            g.pending.coalesced += 1
-                            coalesced.append(g.label)
-                            updated.append(g.label)
-                            if self._phase_hooks:
-                                self._phase("coalesce", red=dict(out),
-                                            group=g.label, step=step)
-                    else:
-                        out.update(res)
-                        if had_pending and self._phase_hooks:
-                            self._phase(
-                                "adopt_forced" if forced
-                                else "adopt", red=dict(out), group=g.label,
-                                step=step, overflowed=ovf)
-                        if (had_pending and margin
-                                and not (overdue or scrub_due)
-                                and hg is not None):
-                            hg.note_forced_resolve(g.label, step)
-                        if ovf:
-                            # Speculation missed: the queued program could not
-                            # cover the snapshot (its blocks stayed marked via
-                            # the shadow select).  Run the always-correct full
-                            # program now.
-                            overflowed.append(g.label)
-                        if ovf or due or overdue or deferred or margin or retry:
-                            # Snapshot the freshness clocks *before* the
-                            # bump below: the governor's wedged-dispatch
-                            # abandon rolls back to these, and the batched
-                            # dispatch only runs after this loop.
-                            to_dispatch.append(
-                                (g, bool(not ovf and g.engine.has_queue
-                                         and g.predicted_fits),
-                                 g.last_update_step, g.last_update_time))
-                            g.last_update_step = step
-                            g.last_update_time = now
-                            if due or overdue or margin:
-                                updated.append(g.label)
-                            if overdue and not due:
-                                deadline.append(g.label)
-                elif sync_esc or due or overdue or margin:
-                    if g.pending is not None:
-                        # Rung 4 engaged with an update still in flight
-                        # (e.g. escalation via a reported violation): adopt
-                        # it first — a stale pending resolved *after* the
-                        # blocking pass would clobber newer checksums.
-                        if self._phase_hooks:
+        with trace.span("tick.schedule"):
+            for g in (() if self._remesh is not None else self._protected()):
+                lp = g.policy
+                if step < g.last_update_step:
+                    # The step counter restarted (new serve wave / fresh run
+                    # on a long-lived store): rebase so deadlines keep their
+                    # meaning.
+                    g.last_update_step = 0
+                sp = (scrub_period if scrub_period is not None
+                      else lp.scrub_period_steps)
+                scrub_due = bool(sp and policy_mod.should_scrub(step, sp))
+                if lp.mode == "vilamb":
+                    margin = sync_esc = retry = False
+                    if hg is not None:
+                        # Escalation-ladder rung 1: a wedged in-flight update
+                        # is abandoned (freshness clocks roll back to
+                        # pre-dispatch) and re-dispatched below after a
+                        # bounded backoff.  The retry flag forces the
+                        # dispatch this tick: ``due`` is step-aligned, so
+                        # waiting for the next period boundary would let the
+                        # breaker cool down between retries.
+                        retry = hg.check_pending(g)
+                        sync_esc = hg.is_sync_escalated(g.label)
+                        margin = hg.within_margin(g, step, now)
+                    eff = min(lp.period_steps * self._governor.scale,
+                              self.policy.period_cap)
+                    due = policy_mod.should_update(step, eff)
+                    overdue = (
+                        (lp.max_vulnerable_steps > 0
+                         and step - g.last_update_step
+                         >= lp.max_vulnerable_steps)
+                        or (lp.max_vulnerable_seconds > 0
+                            and now - g.last_update_time
+                            >= lp.max_vulnerable_seconds))
+                    if self._async_group(g) and not sync_esc:
+                        # Overlap pipeline: resolve lazily (blocking only when
+                        # a deadline or a scrub forces settled state), then
+                        # keep the pipeline primed with at most one in-flight
+                        # update.
+                        had_pending = g.pending is not None
+                        # Rung 2: within the governor's deadline margin the
+                        # tick stops speculating — resolve blocking and
+                        # re-dispatch, meeting the deadline early instead of
+                        # missing it.
+                        forced = overdue or scrub_due or margin
+                        if had_pending and forced and self._phase_hooks:
+                            # The crash point right before the tick joins the
+                            # dispatcher (launch, then fit signal).
                             self._phase("dispatcher_join", red=dict(out),
                                         group=g.label, step=step)
-                        red_sub, _, _ = self._resolve(
-                            g, {n: out[n] for n in g.names}, wait=True)
-                        out.update(red_sub)
-                    out.update(self._dispatch_blocking(
-                        g, sub_of(g), {n: out[n] for n in g.names}))
-                    g.last_update_step = step
-                    g.last_update_time = now
-                    updated.append(g.label)
-                    if self._phase_hooks:
-                        self._phase("blocking_update", red=dict(out),
-                                    group=g.label, step=step)
-                    if overdue and not due:
-                        deadline.append(g.label)
-            if scrub_due:
-                scrub_groups.append(g)
+                        res, ovf, deferred = self._resolve(
+                            g, {n: out[n] for n in g.names}, wait=forced)
+                        if res is None:
+                            # Still in flight: fold this due tick into it.  The
+                            # deadline clock keeps running, so a wedged device
+                            # eventually forces a blocking resolve via overdue.
+                            if due:
+                                g.pending.coalesced += 1
+                                coalesced.append(g.label)
+                                updated.append(g.label)
+                                if self._phase_hooks:
+                                    self._phase("coalesce", red=dict(out),
+                                                group=g.label, step=step)
+                        else:
+                            out.update(res)
+                            if had_pending and self._phase_hooks:
+                                self._phase(
+                                    "adopt_forced" if forced
+                                    else "adopt", red=dict(out), group=g.label,
+                                    step=step, overflowed=ovf)
+                            if (had_pending and margin
+                                    and not (overdue or scrub_due)
+                                    and hg is not None):
+                                hg.note_forced_resolve(g.label, step)
+                            if ovf:
+                                # Speculation missed: the queued program
+                                # could not cover the snapshot (its blocks
+                                # stayed marked via the shadow select).  Run
+                                # the always-correct full program now.
+                                overflowed.append(g.label)
+                            if (ovf or due or overdue or deferred or margin
+                                    or retry):
+                                # Snapshot the freshness clocks *before* the
+                                # bump below: the governor's wedged-dispatch
+                                # abandon rolls back to these, and the batched
+                                # dispatch only runs after this loop.
+                                to_dispatch.append(
+                                    (g, bool(not ovf and g.engine.has_queue
+                                             and g.predicted_fits),
+                                     g.last_update_step, g.last_update_time))
+                                g.last_update_step = step
+                                g.last_update_time = now
+                                if due or overdue or margin:
+                                    updated.append(g.label)
+                                if overdue and not due:
+                                    deadline.append(g.label)
+                    elif sync_esc or due or overdue or margin:
+                        if g.pending is not None:
+                            # Rung 4 engaged with an update still in flight
+                            # (e.g. escalation via a reported violation): adopt
+                            # it first — a stale pending resolved *after* the
+                            # blocking pass would clobber newer checksums.
+                            if self._phase_hooks:
+                                self._phase("dispatcher_join", red=dict(out),
+                                            group=g.label, step=step)
+                            red_sub, _, _ = self._resolve(
+                                g, {n: out[n] for n in g.names}, wait=True)
+                            out.update(red_sub)
+                        with trace.span("tick.dispatch"):
+                            out.update(self._dispatch_blocking(
+                                g, sub_of(g),
+                                {n: out[n] for n in g.names}))
+                        g.last_update_step = step
+                        g.last_update_time = now
+                        updated.append(g.label)
+                        if self._phase_hooks:
+                            self._phase("blocking_update", red=dict(out),
+                                        group=g.label, step=step)
+                        if overdue and not due:
+                            deadline.append(g.label)
+                if scrub_due:
+                    scrub_groups.append(g)
         if to_dispatch:
             # The tentpole: every due group launches in ONE batched
             # multi-group program with one stacked fits vector, its fit
@@ -1481,20 +1567,23 @@ class ProtectedStore:
             if self._phase_hooks:
                 self._phase("dispatcher_enqueue", red=dict(out), step=step,
                             groups=tuple(g.label for g, *_ in to_dispatch))
-            out.update(self._dispatch_async_many(
-                to_dispatch, get_leaves, out, step))
+            with trace.span("tick.dispatch"):
+                out.update(self._dispatch_async_many(
+                    to_dispatch, get_leaves, out, step))
             if self._phase_hooks:
                 for g, *_ in to_dispatch:
                     self._phase("dispatch", red=dict(out), group=g.label,
                                 step=step, queued=g.pending.queued)
-        for g in scrub_groups:
-            mm, alarms = self._scrub_group(g, sub_of(g), out)
-            scrubbed.append(g.label)
-            report.mismatches += mm
-            report.alarms += alarms
-            if self._phase_hooks:
-                self._phase("scrub", red=dict(out), group=g.label,
-                            step=step, mismatches=mm)
+        if scrub_groups:
+            with trace.span("tick.scrub"):
+                for g in scrub_groups:
+                    mm, alarms = self._scrub_group(g, sub_of(g), out)
+                    scrubbed.append(g.label)
+                    report.mismatches += mm
+                    report.alarms += alarms
+                    if self._phase_hooks:
+                        self._phase("scrub", red=dict(out), group=g.label,
+                                    step=step, mismatches=mm)
         report.updated = tuple(updated)
         report.deadline_fired = tuple(deadline)
         report.scrubbed = tuple(scrubbed)
@@ -1506,48 +1595,52 @@ class ProtectedStore:
         # patroller is skipped entirely (its parity geometry is tied to the
         # old mesh; a fresh patroller is built at adoption).
         ran_remesh = False
-        if (self._remesh is None and self._remesh_request is not None
-                and (self.patroller is None
-                     or (self.patroller.rebuild is None
-                         and not self.patroller._pending_loss))):
-            self._remesh_start(get_leaves(), out, step, report)
-        if self._remesh is not None:
-            lv = dict(get_leaves())
-            lv.update(report.repaired)      # moved leaves, if started now
-            self._remesh_step(lv, out, report, step)
-            ran_remesh = True
-        if hg is not None and ran_remesh:
-            # The group loop was suspended this tick (old-geometry red is
-            # authoritative until adoption) — the one window the ladder
-            # above cannot cover.  When a group's freshness margin expired
-            # mid-migration, drain the remaining windows synchronously
-            # (remesh_drain, rung 2: the SLO beats the bounded per-tick
-            # window), then run blocking updates post-adoption.  With
-            # remesh_drain=False the migration keeps its bound and end_tick
-            # reports the violation instead — never silent either way.
-            forced = hg.remesh_overdue(step, now)
-            if forced and self._remesh is not None and hg.hp.remesh_drain:
+        with (trace.span("remesh") if self.remeshing
+              else contextlib.nullcontext()):
+            if (self._remesh is None and self._remesh_request is not None
+                    and (self.patroller is None
+                         or (self.patroller.rebuild is None
+                             and not self.patroller._pending_loss))):
+                self._remesh_start(get_leaves(), out, step, report)
+            if self._remesh is not None:
                 lv = dict(get_leaves())
-                lv.update(report.repaired)
-                while self._remesh is not None:
-                    self._remesh_step(lv, out, report, step)
-            if forced and self._remesh is None:
-                lv = dict(get_leaves())
-                lv.update(report.repaired)   # moved leaves (new geometry)
-                extra = []
-                for g in self._protected():
-                    if g.policy.mode != "vilamb" or g.label not in forced:
-                        continue
-                    out.update(self._dispatch_blocking(
-                        g, {n: lv[n] for n in g.names},
-                        {n: out[n] for n in g.names}))
-                    g.last_update_step = step
-                    g.last_update_time = now
-                    extra.append(g.label)
-                    hg.note_remesh_drain(g.label, step)
-                report.updated = report.updated + tuple(extra)
-                report.deadline_fired = report.deadline_fired + tuple(extra)
-                updated.extend(extra)
+                lv.update(report.repaired)      # moved leaves, if started now
+                self._remesh_step(lv, out, report, step)
+                ran_remesh = True
+            if hg is not None and ran_remesh:
+                # The group loop was suspended this tick (old-geometry red is
+                # authoritative until adoption) — the one window the ladder
+                # above cannot cover.  When a group's freshness margin expired
+                # mid-migration, drain the remaining windows synchronously
+                # (remesh_drain, rung 2: the SLO beats the bounded per-tick
+                # window), then run blocking updates post-adoption.  With
+                # remesh_drain=False the migration keeps its bound and end_tick
+                # reports the violation instead — never silent either way.
+                forced = hg.remesh_overdue(step, now)
+                if forced and self._remesh is not None and hg.hp.remesh_drain:
+                    lv = dict(get_leaves())
+                    lv.update(report.repaired)
+                    while self._remesh is not None:
+                        self._remesh_step(lv, out, report, step)
+                if forced and self._remesh is None:
+                    lv = dict(get_leaves())
+                    lv.update(report.repaired)   # moved leaves (new geometry)
+                    extra = []
+                    for g in self._protected():
+                        if g.policy.mode != "vilamb" or g.label not in forced:
+                            continue
+                        with trace.span("tick.dispatch"):
+                            out.update(self._dispatch_blocking(
+                                g, {n: lv[n] for n in g.names},
+                                {n: out[n] for n in g.names}))
+                        g.last_update_step = step
+                        g.last_update_time = now
+                        extra.append(g.label)
+                        hg.note_remesh_drain(g.label, step)
+                    report.updated = report.updated + tuple(extra)
+                    report.deadline_fired = (report.deadline_fired
+                                             + tuple(extra))
+                    updated.extend(extra)
         if self.patroller is not None and not ran_remesh:
             # Low-priority background duty, after every foreground decision:
             # the patroller sees the post-dispatch live view (in-flight
@@ -1560,9 +1653,10 @@ class ProtectedStore:
             # A queued (not yet started) remesh also counts as busy: the
             # ladder puts remesh above patrol, so probes defer while a
             # geometry change is waiting on an active rebuild to finish.
-            self.patroller.on_tick(
-                get_leaves, out, step, report,
-                busy=bool(updated) or self._remesh_request is not None)
+            with trace.span("patrol"):
+                self.patroller.on_tick(
+                    get_leaves, out, step, report,
+                    busy=bool(updated) or self._remesh_request is not None)
         if hg is not None:
             # Age audit + breaker transitions; attaches report.health and
             # raises FreshnessViolationError only when the ladder is
@@ -1583,6 +1677,11 @@ class ProtectedStore:
         bitwise-identical to the blocking path's flush.  Pass ``step`` when
         known so the steps-based freshness deadline does not fire a
         spurious pass right after the flush."""
+        with trace.span("flush"):
+            return self._flush(leaves, red, step)
+
+    def _flush(self, leaves, red: RedundancyState,
+               step: Optional[int]) -> RedundancyState:
         out = dict(red)
         leaves = self._drain_background(dict(leaves), out, step=step)
         now = time.monotonic()
@@ -1672,11 +1771,7 @@ class ProtectedStore:
                 g, {n: out[n] for n in g.names}, wait=True)
             out.update(red_sub)
             if ovf:
-                repaired, fits = self._update_fn(g.label, "async_full")(
-                    {n: leaves[n] for n in g.names},
-                    {n: out[n] for n in g.names})
-                g.predicted_fits = _fits_host(fits)
-                out.update(repaired)
+                self._full_fallback(g, leaves, out)
         # The migration swaps engines and jit caches at adoption; the old
         # geometry's dispatcher (and any compiled programs its queued jobs
         # closed over) must not leak across the handover.
@@ -1718,14 +1813,16 @@ class ProtectedStore:
         fn = self._scrub_fn(g.label)
         red_sub = {n: red[n] for n in g.names}
         mm = fn(sub, red_sub)
-        total = int(sum(int(v.sum()) for v in jax.tree.leaves(mm)))
+        with trace.waited(self.counters, "scrub"):
+            total = int(sum(int(v.sum()) for v in jax.tree.leaves(mm)))
         alarms = 0
         if total:
             # Double-check (paper §3.4): quiesce in-flight work, re-verify on
             # an immutable snapshot before raising the alarm.
-            jax.block_until_ready(sub)
-            mm = fn(sub, red_sub)
-            total = int(sum(int(v.sum()) for v in jax.tree.leaves(mm)))
+            with trace.waited(self.counters, "scrub"):
+                jax.block_until_ready(sub)
+                mm = fn(sub, red_sub)
+                total = int(sum(int(v.sum()) for v in jax.tree.leaves(mm)))
             if total:
                 alarms = 1
                 self.corruption_alarms += 1

@@ -210,7 +210,7 @@ def patrol_pass(seed: int, steps: int) -> int:
     ok = detected and repaired and clean and bitwise
     diag = ("" if ok else
             f" [budget={budget} starved={pat.starved_ticks} "
-            f"sweeps={dict(pat.sweeps)} scanned={pat.blocks_scanned} "
+            f"sweeps={dict(pat.sweeps)} scanned={store.counters['patrol.blocks_scanned']} "
             f"probe_out={pat._probe is not None}]")
     print(f"  patrol seed={seed}: detected={detected} (latency "
           f"{lat['mean_s']:.0f} ticks) repaired={repaired} clean={clean} "

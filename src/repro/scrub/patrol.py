@@ -38,7 +38,6 @@ row is never validated over a fresh write.
 from __future__ import annotations
 
 import collections
-import dataclasses
 import math
 import warnings
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
@@ -47,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.repairs import (UnrecoverableBlock, plan_stripe_repairs,
                                 repair_blocks, vulnerable_unrecoverable)
 from repro.core.store import _ready
@@ -61,9 +61,9 @@ from .rebuild import CrossShardParity, ShardRebuilder, xor_fold as _xor_fold
 # declared lost.
 MAX_REPAIR_ATTEMPTS = 3
 
-# Bound on the observability histories (detections, measured latencies) so
-# a long-running store does not grow them without limit; the MTTDL model
-# only ever wants recent-window statistics anyway.
+# Bound on the measured-latency history so a long-running store does not
+# grow it without limit; the MTTDL model only ever wants recent-window
+# statistics anyway.
 OBSERVABILITY_CAP = 4096
 
 # A probe outstanding this many process attempts with ``is_ready`` still
@@ -91,17 +91,6 @@ class ShardLossConflictError(RuntimeError):
             f"{active_shard} is still rebuilding; cross-shard parity "
             "covers a single lost shard, so a concurrent second loss is "
             "unrecoverable (wait for the active rebuild to finish)")
-
-
-@dataclasses.dataclass(frozen=True)
-class DetectionEvent:
-    """One patrol detection: leaf, global block id, detection step, and —
-    when the corruption was registered via :meth:`ScrubPatroller.
-    expect_injection` — the measured latency in steps."""
-    leaf: str
-    block: int
-    step: int
-    latency_steps: Optional[int] = None
 
 
 class ScrubPatroller:
@@ -166,16 +155,14 @@ class ScrubPatroller:
         # Queued losses: (name, shard, preloss-row-mask-or-None).
         self._pending_loss: List[Tuple[str, int, Optional[np.ndarray]]] = []
         self.rebuild: Optional[ShardRebuilder] = None
-        # Observability.
+        # Observability.  The store's counters take ``patrol.blocks_scanned``
+        # (local probe positions covered) and the probe resolutions:
+        # ``patrol.probes_ready`` (adopted once ``is_ready`` said so) and
+        # ``patrol.probes_forced`` (force-fetched after PROBE_FORCE_TICKS
+        # not-ready attempts).
+        self.counters = store.counters
         self.ticks = 0
-        self.blocks_scanned = 0            # local probe positions covered
         self.starved_ticks = 0             # consecutive ticks with no probe
-        # Probe resolutions: adopted once ``is_ready`` said so, vs force-
-        # fetched after PROBE_FORCE_TICKS not-ready attempts.
-        self.probes_ready = 0
-        self.probes_forced = 0
-        self.detections: collections.deque = collections.deque(
-            maxlen=OBSERVABILITY_CAP)
         self.latencies: collections.deque = collections.deque(
             maxlen=OBSERVABILITY_CAP)      # steps, registered injections only
         self.unrecoverable: List[UnrecoverableBlock] = []
@@ -199,7 +186,8 @@ class ScrubPatroller:
         sees every mark through step ``t``."""
         meta = self.store.metas[name]
         k = self.store.shard_factor(name)
-        live = np.asarray(r.dirty) | np.asarray(r.shadow)
+        with trace.waited(self.counters, "live_rows"):
+            live = np.asarray(r.dirty) | np.asarray(r.shadow)
         return bits_to_mask(live, meta.n_blocks,
                             shards=k).reshape(k, meta.n_blocks)
 
@@ -292,12 +280,45 @@ class ScrubPatroller:
             return overlay
 
         if not self._primed:
-            self._prime(lv(), out)
+            with trace.span("patrol.probe"):
+                self._prime(lv(), out)
             self._primed = True
         # Invalidate-then-validate: write samples first, so a probe result
         # never re-validates a cross-shard parity row over a fresh write.
-        self._process_sample()
-        self._process_probe(out, step, report)
+        if self._sample is not None:
+            with trace.span("patrol.sample"):
+                self._process_sample()
+        if self._probe is not None:
+            with trace.span("patrol.probe"):
+                self._process_probe(out, step, report)
+        if (self.rebuild is not None or self._pending_loss
+                or self._repair_queue):
+            with trace.span("patrol.repair"):
+                self._repair(lv, out, step, report)
+        if self.xpar:
+            with trace.span("patrol.sample"):
+                self._dispatch_sample(out)
+        # Busy ticks defer the probe, but only up to the starvation floor:
+        # under wall-to-wall update traffic the patrol would otherwise
+        # never run and detection latency silently degrades to the
+        # scheduled-scrub baseline.  After ``patrol_max_starved_ticks``
+        # consecutive probe-less ticks one probe dispatches anyway
+        # (0 disables the floor; rebuilds still take priority).
+        floor = int(self.store.policy.patrol_max_starved_ticks)
+        forced = floor > 0 and self.starved_ticks >= floor
+        if ((not busy or forced) and self._probe is None
+                and self.rebuild is None and self.targets):
+            with trace.span("patrol.probe"):
+                self._dispatch_probe(lv(), out, step, report)
+            self.starved_ticks = 0
+        elif self._probe is None and self.targets:
+            self.starved_ticks += 1
+        report.patrol_starved_ticks = self.starved_ticks
+
+    # ------------------------------------------------------------- internals
+    def _repair(self, lv, out, step: int, report) -> None:
+        """Loss recovery first (start or advance the shard rebuild), else
+        the paced per-block parity repairs."""
         if self.rebuild is None and self._pending_loss:
             self._start_rebuild(lv(), out, step)
         if self.rebuild is not None:
@@ -309,24 +330,7 @@ class ScrubPatroller:
                 self.rebuild = None
         elif self._repair_queue:
             self._run_repairs(lv, out, report)
-        self._dispatch_sample(out)
-        # Busy ticks defer the probe, but only up to the starvation floor:
-        # under wall-to-wall update traffic the patrol would otherwise
-        # never run and detection latency silently degrades to the
-        # scheduled-scrub baseline.  After ``patrol_max_starved_ticks``
-        # consecutive probe-less ticks one probe dispatches anyway
-        # (0 disables the floor; rebuilds still take priority).
-        floor = int(self.store.policy.patrol_max_starved_ticks)
-        forced = floor > 0 and self.starved_ticks >= floor
-        if ((not busy or forced) and self._probe is None
-                and self.rebuild is None and self.targets):
-            self._dispatch_probe(lv(), out, step, report)
-            self.starved_ticks = 0
-        elif self._probe is None and self.targets:
-            self.starved_ticks += 1
-        report.patrol_starved_ticks = self.starved_ticks
 
-    # ------------------------------------------------------------- internals
     def _prime(self, leaves, out) -> None:
         """First tick: fold the initial cross-shard parity image per
         eligible leaf and seed row validity from the live bitvectors."""
@@ -343,7 +347,9 @@ class ScrubPatroller:
         for name, words in self._sample.items():
             meta = self.store.metas[name]
             k = self.store.shard_factor(name)
-            rows = bits_to_mask(np.asarray(words), meta.n_blocks,
+            with trace.waited(self.counters, "sample"):
+                words = np.asarray(words)
+            rows = bits_to_mask(words, meta.n_blocks,
                                 shards=k).reshape(k, meta.n_blocks)
             written = rows.any(axis=0)
             self.xpar[name].xvalid &= ~written
@@ -401,7 +407,7 @@ class ScrubPatroller:
                 pass
         self._probe = (name, start, w, mism, clean, xwin, step)
         self._probe_inval = (np.zeros((nb,), bool) if want_slab else None)
-        self.blocks_scanned += w
+        trace.add(self.counters, "patrol.blocks_scanned", w)
         self.cursor[name] = start + w
         if self.cursor[name] >= nb:
             self.cursor[name] = 0
@@ -419,10 +425,11 @@ class ScrubPatroller:
             # Stuck past any plausible execution time: force the (tiny)
             # fetch instead of trusting a readiness notification that may
             # never arrive — see PROBE_FORCE_TICKS.
-            np.asarray(mism_d), np.asarray(clean_d)
-            self.probes_forced += 1
+            with trace.waited(self.counters, "probe"):
+                np.asarray(mism_d), np.asarray(clean_d)
+            trace.add(self.counters, "patrol.probes_forced")
         else:
-            self.probes_ready += 1
+            trace.add(self.counters, "patrol.probes_ready")
         self._probe_stuck = 0
         self._probe = None
         inval, self._probe_inval = self._probe_inval, None
@@ -501,7 +508,6 @@ class ScrubPatroller:
         lat = (step - inj) if inj is not None else None
         if lat is not None:
             self.latencies.append(int(lat))
-        self.detections.append(DetectionEvent(name, gblock, int(step), lat))
         self._repair_queue.append([name, gblock, 0])
 
     def _start_rebuild(self, leaves, out, step: int) -> None:

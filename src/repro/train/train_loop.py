@@ -141,7 +141,6 @@ class Trainer:
         self.scrub_fn = ((lambda state: self.store.scrub(
             protected_leaves(state.params, state.opt), state.red))
             if self.store is not None else None)
-        self.step_times: list = []
 
     @property
     def corruption_alarms(self) -> int:
@@ -195,7 +194,6 @@ class Trainer:
             state, metrics = self.train_step(state, batch)
             jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
-            self.step_times.append(dt)
             if self.store is not None:
                 st = state
                 red, report = self.store.tick(

@@ -216,7 +216,8 @@ def test_no_queue_fits_round_trip_on_async_hot_path(monkeypatch):
         raise AssertionError("queue_fits called on the async hot path")
 
     for g in store.groups.values():
-        monkeypatch.setattr(g.engine, "queue_fits", boom)
+        # queue_fits goes through queue_check, the blocking path's probe
+        monkeypatch.setattr(g.engine, "queue_check", boom)
     for step in range(1, 6):
         red = store.on_write(red, events={"w": jnp.zeros((24,), bool)
                                           .at[step % 24].set(True)})

@@ -173,10 +173,10 @@ def test_inline_fallback_fetch_happens_at_dispatch_not_resolve():
         fn = orig_many(labels, variants)
 
         def call(subs, reds):
-            outs, fits = fn(subs, reds)
+            outs, fits, stripes = fn(subs, reds)
             proxy = _NoAsyncFits(fits)
             proxies.append(proxy)
-            return outs, proxy
+            return outs, proxy, np.asarray(stripes)
 
         return call
 

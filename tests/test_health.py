@@ -321,7 +321,7 @@ def test_patrol_floor_survives_backpressure():
         red, rep = store.tick(lv, red, step, step_time=0.01, scrub_period=0)
         assert rep.updated, "tick unexpectedly quiet"
         assert rep.health.patrol_starved_ticks == rep.patrol_starved_ticks
-    assert store.patroller.blocks_scanned >= 8   # floor forced probes
+    assert store.counters["patrol.blocks_scanned"] >= 8   # floor forced probes
     assert rep.patrol_starved_ticks <= 4
     assert spins == [0.001] * 30                 # every admit spun, none raised
 

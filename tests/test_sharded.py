@@ -57,10 +57,11 @@ def test_sharded_queued_and_async_programs_are_collective_free():
             lowered = store._build_update_many(
                 (g.label,), (variant,)).lower((lv,), (red,))
             assert_no_collectives(lowered, "many_" + variant)
-        outs, stacked = store._update_many_fn(
+        outs, stacked, counts = store._update_many_fn(
             (g.label,), ("async_queued",))((lv,), (red,))
         # one row per group, one flag column per device
         assert stacked.shape == (1, 8), stacked.shape
+        assert counts.shape == (1, 8), counts.shape
         assert workqueue.fold_fits_host(np.asarray(stacked)[0])
         print("PROGRAMS_OK")
     """, "PROGRAMS_OK", prelude=MESH_PRELUDE)
@@ -109,13 +110,13 @@ def test_sharded_async_hot_path_never_pays_queue_fits_round_trip():
         def boom(*a, **k):
             raise AssertionError("queue_fits called on the sharded async hot path")
         for g in store._protected():
-            g.engine.queue_fits = boom
+            g.engine.queue_check = boom      # queue_fits goes through it
         lv, red = drive(store, steps=6, seed=2)
         g = next(iter(store.groups.values()))
         assert g.pending is None or g.pending.fits.shape == (1, 8), \
             "pending fit signal must be the batched per-shard row"
         for g in store._protected():
-            del g.engine.queue_fits          # settle may use the exact check
+            del g.engine.queue_check         # settle may use the exact check
         red = store.settle(red, lv)
         assert sum(int(v.sum()) for v in store.scrub(lv, red).values()) == 0
         print("HOTPATH_OK")
