@@ -393,6 +393,7 @@ def sharded_phase(rows: int = 1 << 18, steps: int = 60, seed: int = 0,
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import LeafPolicy, ProtectedStore
+    from repro.core.store import _queued_variant
     from repro.faults import FaultSpec
     from repro.launch.hlo_analysis import assert_no_collectives
     from repro.launch.mesh import make_mesh
@@ -458,13 +459,19 @@ def sharded_phase(rows: int = 1 << 18, steps: int = 60, seed: int = 0,
     check(any(isinstance(k[0], tuple) and len(k[0]) > 1
               for k in store._jit_update),
           "a batched multi-group update was dispatched")
-    for variant in ("async_full", "async_queued"):
-        groups = [store.groups[l] for l in labels]
+    groups = [store.groups[l] for l in labels]
+    ladders = [g.engine.queue_ladder for g in groups]
+    for what, variants in (
+            ("full", ("async_full",) * len(groups)),
+            ("top rung", tuple(_queued_variant(k[-1]) if k else "async_full"
+                               for k in ladders)),
+            ("bottom rung", tuple(_queued_variant(k[0]) if k else "async_full"
+                                  for k in ladders))):
         assert_no_collectives(store._build_update_many(
-            labels, (variant,) * len(labels)).lower(
+            labels, variants).lower(
             tuple({n: heaps[n] for n in g.names} for g in groups),
             tuple({n: red[n] for n in g.names} for g in groups)),
-            f"batched {variant} update")
+            f"batched {what} update")
 
     # Quiet ticks until cross-shard parity covers every heap, then lose one
     # shard of h0 and rebuild it while writes land in that shard.

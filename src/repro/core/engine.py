@@ -126,6 +126,12 @@ class RedundancyEngine:
                 meta.n_stripes, config.work_queue_frac)
             for name, meta in self.metas.items()
         }
+        # The compiled capacities (rungs) of the queued overlap program,
+        # ascending; empty without a queue.  Derived from the largest
+        # leaf's capacity: at rung K each queued leaf takes min(K, its own
+        # capacity) (_rung_cap), so the top rung is the leaves' own.
+        self.queue_ladder: Tuple[int, ...] = workqueue.queue_ladder(
+            max(self._queue_caps.values(), default=0))
         self._queue_fits_jit = None
 
     # ------------------------------------------------------------------ utils
@@ -203,6 +209,17 @@ class RedundancyEngine:
         """Static work-queue capacity (stripes) for a leaf; 0 = no queue."""
         return self._queue_caps[name]
 
+    def _rung_cap(self, name: str, rung: Optional[int]) -> int:
+        """A leaf's queue capacity at ``rung`` (None = the top)."""
+        cap = self._queue_caps[name]
+        return cap if rung is None or not cap else min(rung, cap)
+
+    def queue_rows(self, rung: Optional[int] = None) -> int:
+        """Queue rows one queued pass at ``rung`` pays for, over its leaves
+        and shards (padding included): the ``K`` of every queue."""
+        return sum(self._rung_cap(n, rung) * self.shard_factor(n)
+                   for n in self.metas)
+
     @property
     def has_queue(self) -> bool:
         """Whether the queued Algorithm-1 variant exists for this engine.
@@ -262,21 +279,21 @@ class RedundancyEngine:
         p = self.config.stripe_data_blocks
         return p * block + block + p * 4
 
-    def _update_leaf(self, name: str, meta: BlockMeta, lanes,
-                     old: LeafRedundancy, bdirty, sdirty, queued: bool):
+    def _update_leaf(self, meta: BlockMeta, lanes, old: LeafRedundancy,
+                     bdirty, sdirty, cap: int):
         """Masked checksum+parity+meta refresh (Alg. 1 lines 7-22).
 
         Three interchangeable bitwise-identical realizations: the Pallas
-        fused kernel, the XLA work-queue compaction (cost ∝ dirty stripes;
-        caller guarantees the fit), or the full-region masked recompute.
+        fused kernel, the XLA work-queue compaction of capacity ``cap``
+        (cost ∝ ``cap``; caller guarantees the fit), or the full-region
+        masked recompute (``cap`` 0).
         """
         if self._kernel_ops is not None:
             cks, par = self._kernel_ops.fused_update(
                 lanes, old.checksums, old.parity, bdirty, sdirty,
                 meta.stripe_data_blocks, interpret=self.config.kernel_interpret)
             return cks, par, checksum.meta_checksum(cks)
-        cap = self._queue_caps[name]
-        if queued and cap:
+        if cap:
             ids, _, _ = workqueue.compact_stripe_ids(sdirty, cap)
             return workqueue.queued_update(
                 lanes, old.checksums, old.parity, old.meta_ck, bdirty, ids,
@@ -369,38 +386,48 @@ class RedundancyEngine:
         return fn(red, arr_events)
 
     # -------------------------------------------------- Algorithm 1 (vilamb)
-    def _alg1_parts(self, ls, red_l, queued: bool, want_fits: bool):
+    def _alg1_parts(self, ls, red_l, queued: bool, want_fits: bool,
+                    rung: Optional[int] = None):
         """Shared Algorithm-1 body (traceable): per-leaf masked update.
 
         Lines 2-4: snapshot ``dirty | shadow`` (include leftover shadow
         from a crash); lines 7-18 + 22: masked checksum + parity recompute
         with the meta-checksum refreshed incrementally on the work-queue
         path.  Returns ``({name: (cks, par, meta_ck, snapshot)}, fits,
-        stripes)`` — the blocking and overlap entry points differ only in
+        counts)`` — the blocking and overlap entry points differ only in
         how they fold these into dirty/shadow outputs.  ``fits`` (the
-        device-side queue-fit predicate over every queued leaf) and
-        ``stripes`` (the dirty stripes this pass covers, int32) are only
-        evaluated when requested.
+        device-side queue-fit predicate over every queued leaf, against
+        its capacity at ``rung`` when queued, else at the top) and
+        ``counts`` are only evaluated when requested.  ``counts`` is int32
+        ``[stripes, need]``: the dirty stripes this pass covers, summed
+        over leaves, and the smallest rung that would hold the snapshot —
+        the largest queued leaf's count, or ``workqueue.NO_RUNG`` where a
+        leaf's count exceeds its own top capacity.
         """
         parts: Dict[str, Tuple] = {}
         fits = []
-        stripes = jnp.int32(0)
+        stripes = need = jnp.int32(0)
         for name, meta in self.metas.items():
             r = red_l[name]
             snapshot = jnp.bitwise_or(r.dirty, r.shadow)
             bdirty = bits.unpack(snapshot, meta.n_blocks)
             sdirty = self._stripe_dirty(meta, bdirty)
-            cap = self._queue_caps[name]
+            cap = self._rung_cap(name, rung if queued else None)
             if want_fits and cap:
                 fits.append(workqueue.stripe_fits(sdirty, cap))
             if want_fits:
-                stripes = stripes + workqueue.stripe_dirty_count(sdirty)
+                n = workqueue.stripe_dirty_count(sdirty)
+                stripes = stripes + n
+                top = self._queue_caps[name]
+                if top:
+                    need = jnp.maximum(need, jnp.where(
+                        n <= top, n, jnp.int32(workqueue.NO_RUNG)))
             lanes = blocks.to_lanes(ls[name], meta)
             cks, par, meta_ck = self._update_leaf(
-                name, meta, lanes, r, bdirty, sdirty, queued)
+                meta, lanes, r, bdirty, sdirty, cap if queued else 0)
             parts[name] = (cks, par, meta_ck, snapshot)
         fits_all = jnp.all(jnp.stack(fits)) if fits else jnp.asarray(True)
-        return parts, fits_all, stripes
+        return parts, fits_all, jnp.stack([stripes, need])
 
     def _alg1(self, leaves, red: RedundancyState, queued: bool
               ) -> RedundancyState:
@@ -456,20 +483,23 @@ class RedundancyEngine:
     # ------------------------------------------- Algorithm 1, overlap form
     def redundancy_step_async(
         self, leaves: Mapping[str, jax.Array], red: RedundancyState,
-        queued: bool = False,
+        queued: bool = False, rung: Optional[int] = None,
     ) -> Tuple[RedundancyState, jax.Array, jax.Array]:
         """Algorithm 1 restructured for sync-free overlapped dispatch.
 
+        ``rung`` (queued only; None = the top) is the queue capacity this
+        program compiles, one of :attr:`queue_ladder`.
+
         Same snapshot-merge and per-leaf math as :meth:`redundancy_step` /
         :meth:`redundancy_step_queued` — one donated in-place program — but
-        returning ``(red_out, fits, stripes)`` so no host check guards
+        returning ``(red_out, fits, counts)`` so no host check guards
         adoption:
 
         * ``fits`` is the device-computed queue-fit predicate
-          (``queue_fits`` without the host round trip); the dispatcher
-          fetches it via a non-blocking async copy and uses it one tick
-          ahead as the speculation signal for the *next* queued-vs-full
-          choice, and retrospectively as the overflow flag for *this* one.
+          (``queue_fits`` without the host round trip, against the queues
+          at ``rung``); the dispatcher fetches it via a non-blocking async
+          copy and uses it retrospectively as the overflow flag for *this*
+          pass, and one tick ahead as the speculation signal for the next.
         * The returned state is valid **unconditionally**.  Under
           ``queued=True`` the scattered checksums/parity are correct fresh
           values for every stripe that made the queue; ``red_out.shadow``
@@ -480,14 +510,18 @@ class RedundancyEngine:
           bitmap the foreground's next ``on_write`` marks into.
           :meth:`redundancy_step_queued`'s "never unguarded" contract is
           thus discharged on device.
-        * ``stripes`` is the int32 count of dirty stripes in the snapshot
-          (the work this pass covers when it does not overflow), fetched
-          with ``fits`` in the same transfer.
+        * ``counts`` is int32 ``[stripes, need]``, fetched with ``fits`` in
+          the same transfer: the dirty stripes in the snapshot (the work
+          this pass covers when it does not overflow) and the smallest
+          queue capacity that holds every leaf's share of them
+          (:meth:`_alg1_parts`), from which the dispatcher sizes the next
+          pass's rung.
 
         Under a mesh the whole body runs per shard inside ``shard_map``
         (zero collectives): each shard compacts its own queue, and ``fits``
         is the **per-shard** flag array (global shape ``(n_devices,)``,
-        sharded over every mesh axis), as is ``stripes``.  The overflow
+        sharded over every mesh axis), and ``counts`` one row per device,
+        ``(n_devices, 2)``.  The overflow
         select is per shard too — only the shards whose local queue
         overflowed keep their snapshot marked.  Dispatchers never fold the flags on device: the
         store stacks them into its batched fits vector and AND-folds the
@@ -497,8 +531,8 @@ class RedundancyEngine:
         collective-free.
         """
         def local(ls, red_l):
-            parts, fits_all, stripes = self._alg1_parts(ls, red_l, queued,
-                                                        want_fits=True)
+            parts, fits_all, counts = self._alg1_parts(
+                ls, red_l, queued, want_fits=True, rung=rung)
             overflowed = (jnp.logical_not(fits_all) if queued
                           else jnp.asarray(False))
             out: RedundancyState = {}
@@ -512,8 +546,8 @@ class RedundancyEngine:
                 )
             if self.mesh is not None:
                 fits_all = fits_all.reshape((1,))
-                stripes = stripes.reshape((1,))
-            return out, fits_all, stripes
+                counts = counts.reshape((1, 2))
+            return out, fits_all, counts
 
         if self.mesh is None:
             return local(dict(leaves), red)

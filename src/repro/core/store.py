@@ -350,10 +350,11 @@ class _Pending:
     launched: Optional[threading.Event] = None
     fits_index: int = 0
     fits_host: Optional[bool] = None
-    # The batch's stacked dirty-stripe counts (same layout as ``fits``)
-    # and this group's host sum, published with ``fits_host``.
-    stripes: Any = None
-    stripes_host: Optional[int] = None
+    # The batch's stacked ``[stripes, need]`` counts (``fits``' layout
+    # plus a trailing pair; engine.redundancy_step_async) and this group's
+    # host row, published with ``fits_host``.
+    counts: Any = None
+    counts_host: Optional[np.ndarray] = None
     error: Optional[BaseException] = None
     # Health-governor bookkeeping: wall-clock dispatch timestamp (wedged-
     # dispatch detection) and the group's freshness clocks as they stood
@@ -403,23 +404,34 @@ def _fits_host_pending(p: "_Pending") -> bool:
     return workqueue.fold_fits_host(arr[p.fits_index] if arr.ndim else arr)
 
 
-def _stripes_host_pending(p: "_Pending") -> int:
-    """This group's dirty-stripe count out of the batch's stacked counts
-    (summed over shards): ``stripes_host`` once published, else a host
-    memory read of the landed copy."""
-    if p.stripes_host is not None:
-        return p.stripes_host
-    arr = np.asarray(p.stripes)
-    return int((arr[p.fits_index] if arr.ndim else arr).sum())
+def _counts_host(counts) -> Tuple[int, int]:
+    """``(stripes, need)`` of one group's fetched ``[stripes, need]`` row
+    or rows: the dirty stripes summed over shards, the need of the
+    largest shard (a rung must hold every shard's queue)."""
+    c = np.asarray(counts).reshape(-1, 2)
+    return int(c[:, 0].sum()), int(c[:, 1].max())
 
 
-def _publish(pendings, fits_host, stripes_host) -> None:
-    """Fold a batch's fetched fits and stripe counts into its pendings."""
+def _counts_host_pending(p: "_Pending") -> Tuple[int, int]:
+    """:func:`_counts_host` of this group's row of the batch's stacked
+    counts: ``counts_host`` once published, else a host memory read of
+    the landed copy."""
+    if p.counts_host is not None:
+        return _counts_host(p.counts_host)
+    return _counts_host(np.asarray(p.counts)[p.fits_index])
+
+
+def _queued_variant(rung: int) -> str:
+    """The batched program's variant name of a queued pass at ``rung``."""
+    return f"async_queued@{rung}"
+
+
+def _publish(pendings, fits_host, counts_host) -> None:
+    """Fold a batch's fetched fits and counts into its pendings."""
     for i, p in enumerate(pendings):
         f = fits_host[i] if fits_host.ndim else fits_host
-        n = stripes_host[i] if stripes_host.ndim else stripes_host
         p.fits_host = workqueue.fold_fits_host(f)
-        p.stripes_host = int(np.sum(n))
+        p.counts_host = counts_host[i]
 
 
 class _Dispatcher:
@@ -470,6 +482,14 @@ class _Group:
     # resolved fit signal (or a flush's exact check) flips it.
     pending: Optional[_Pending] = None
     predicted_fits: bool = False
+    # The queue capacity (rung of ``engine.queue_ladder``) of the in-flight
+    # or last overlapped pass, 0 = the full program; whether that pass
+    # re-dispatches an overflowed one; and the need (``workqueue.pick_rung``)
+    # of the last resolved pass that no due tick coalesced into (None =
+    # unknown: the next pass takes the top).
+    rung: int = 0
+    redispatch: bool = False
+    last_need: Optional[int] = None
 
 
 # ---------------------------------------------------------------------- store
@@ -520,14 +540,16 @@ class ProtectedStore:
         # points for crash-consistency replay.  Empty list = zero overhead
         # on every hot path (a single truthiness check).
         self._phase_hooks: List[Callable[[str, Dict[str, Any]], None]] = []
-        # Always-on host counters (repro.core.trace): Algorithm-1 passes and
+        # Always-on host counters (repro.core.trace): Algorithm-1 passes,
         # the dirty stripes they covered (counted on the device, added at
-        # resolution), patrol probes, and ``wait.<site>.{n,s,max_ms}`` for
-        # every place the tick thread blocks.
+        # resolution) and the queue rows the queued ones paid for, patrol
+        # probes, and ``wait.<site>.{n,s,max_ms}`` for every place the tick
+        # thread blocks.
         self.counters: Dict[str, float] = dict.fromkeys((
             "update.passes_queued", "update.passes_full", "update.overflowed",
-            "update.stripes", "update.alg1_bytes", "patrol.probes_ready",
-            "patrol.probes_forced", "patrol.blocks_scanned"), 0)
+            "update.stripes", "update.queue_rows", "update.alg1_bytes",
+            "patrol.probes_ready", "patrol.probes_forced",
+            "patrol.blocks_scanned"), 0)
 
     # -------------------------------------------------------------- phase hooks
     def add_phase_hook(self, fn: Callable[[str, Dict[str, Any]], None]) -> None:
@@ -817,8 +839,10 @@ class ProtectedStore:
 
         Variants: ``full`` / ``queued`` — the blocking programs (input red
         donated in place; used by ``flush`` and the blocking tick);
-        ``async_full`` / ``async_queued`` — the overlap programs
-        ``(leaves, red) -> (red, fits, stripes)``.  The overlap programs donate
+        ``async_full`` — the overlap program ``(leaves, red) -> (red, fits,
+        counts)`` that settle's fallback runs (the tick's overlap passes,
+        queued ones included, run in :meth:`_build_update_many`'s batched
+        program).  The overlap programs donate
         **nothing**: on this backend a donated dispatch blocks the host
         until its donated inputs are defined, so in-place updates would
         re-serialize the very pipeline the overlap exists to free.  The
@@ -831,10 +855,8 @@ class ProtectedStore:
             return jax.jit(eng.redundancy_step, donate_argnums=(1,))
         if variant == "queued":
             return jax.jit(eng.redundancy_step_queued, donate_argnums=(1,))
-        assert variant in ("async_full", "async_queued"), variant
-        q = variant == "async_queued"
-        return jax.jit(
-            lambda lv, rd, e=eng: e.redundancy_step_async(lv, rd, queued=q))
+        assert variant == "async_full", variant
+        return jax.jit(eng.redundancy_step_async)
 
     def _update_fn(self, label: str, variant: str):
         key = (label, variant)
@@ -846,7 +868,10 @@ class ProtectedStore:
     def _build_update_many(self, labels: Tuple[str, ...],
                            variants: Tuple[str, ...]):
         """One jitted program running every due group's overlap Algorithm-1
-        pass and stacking the fit signals into a single vector.
+        pass and stacking the fit signals into a single vector.  A group's
+        variant is ``async_full`` or ``async_queued@<K>``: the queued pass
+        at rung ``K`` of its engine's ``queue_ladder``
+        (:func:`_queued_variant`).
 
         This is the tentpole of the sharded-overlap fix: a due tick used to
         launch one update program *plus* one AND-fold program per group —
@@ -856,21 +881,27 @@ class ProtectedStore:
         a mesh — pinned to per-device columns so the program still lowers
         collective-free; the AND-fold over shards happens on the host at
         resolution, where the row is already fetched memory).  The groups'
-        dirty-stripe counts come back beside it, an int32 vector of the
-        same layout.
+        int32 ``[stripes, need]`` counts come back beside it, in the same
+        layout with a trailing pair.
         """
         engines = [self.groups[l].engine for l in labels]
-        qs = [v == "async_queued" for v in variants]
+        rungs = []
+        for v in variants:
+            kind, _, k = v.partition("@")
+            assert (kind, bool(k)) in (("async_full", False),
+                                       ("async_queued", True)), v
+            rungs.append(int(k) if k else 0)
         mesh = engines[0].mesh
 
         def many(subs, reds):
-            outs, fits, stripes = [], [], []
-            for eng, q, sub, rd in zip(engines, qs, subs, reds):
-                o, f, n = eng.redundancy_step_async(sub, rd, queued=q)
+            outs, fits, counts = [], [], []
+            for eng, k, sub, rd in zip(engines, rungs, subs, reds):
+                o, f, n = eng.redundancy_step_async(
+                    sub, rd, queued=k > 0, rung=k or None)
                 outs.append(o)
                 fits.append(f)
-                stripes.append(n)
-            stacked, counts = jnp.stack(fits), jnp.stack(stripes)
+                counts.append(n)
+            stacked, counts = jnp.stack(fits), jnp.stack(counts)
             if mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 # Per-shard flag columns stay device-local: each device
@@ -935,8 +966,10 @@ class ProtectedStore:
                     eng.red_structs(global_=True), eng.red_shardings(),
                     is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
             # Async groups also warm the blocking pair: flush (the
-            # latency-critical preemption path) still dispatches it.
-            variants = (("async_full", "async_queued", "full", "queued")
+            # latency-critical preemption path) still dispatches it; and
+            # the single-group full overlap program, settle's fallback.
+            # Their queued overlap passes run in the batched program.
+            variants = (("async_full", "full", "queued")
                         if self._async_group(g) else ("full", "queued"))
             for variant in variants:
                 if "queued" in variant and not eng.has_queue:
@@ -948,13 +981,11 @@ class ProtectedStore:
                     g.label, variant).lower(leaf_structs, red_structs).compile()
             if self._async_group(g):
                 # The tick launches through the batched multi-group program
-                # (a singleton batch when one group is due); AOT-lower both
-                # speculative variants of it too, so the first overlapped
-                # dispatch never hides a compile stall on the dispatcher
-                # thread.
-                for variant in ("async_full", "async_queued"):
-                    if "queued" in variant and not eng.has_queue:
-                        continue
+                # (a singleton batch when one group is due); AOT-lower the
+                # full variant and every rung of the queued one too, so no
+                # overlapped dispatch hides a compile stall.
+                for variant in ("async_full", *map(_queued_variant,
+                                                   eng.queue_ladder)):
                     mkey = ((g.label,), (variant,))
                     if mkey in self._jit_update:
                         continue
@@ -992,23 +1023,47 @@ class ProtectedStore:
             fits, stripes = g.engine.queue_check(red_sub)
         queued = g.engine.has_queue and fits
         g.predicted_fits = queued or not g.engine.has_queue
+        g.last_need = None
         self._count_pass(g, queued, stripes)
         return self._update_fn(g.label, "queued" if queued else "full")(
             sub, red_sub)
 
-    def _count_pass(self, g: _Group, queued: bool,
-                    stripes: Optional[int]) -> None:
+    def _count_pass(self, g: _Group, queued: bool, stripes: Optional[int],
+                    rung: Optional[int] = None) -> None:
         """Count one Algorithm-1 pass; ``stripes`` None = overflowed (its
-        work is redone by the full fallback, which counts itself)."""
+        work is redone by the fallback, which counts itself).  A queued
+        pass adds the queue rows its ``rung`` (None = top) paid for,
+        overflowed or not."""
         c = self.counters
         trace.add(c, "update.passes_queued" if queued
                   else "update.passes_full")
+        if queued:
+            trace.add(c, "update.queue_rows", g.engine.queue_rows(rung))
         if stripes is None:
             trace.add(c, "update.overflowed")
             return
         trace.add(c, "update.stripes", stripes)
         trace.add(c, "update.alg1_bytes",
                   stripes * g.engine.alg1_stripe_bytes)
+
+    def _next_rung(self, g: _Group, overflowed: bool, retry: bool) -> int:
+        """Queue capacity of ``g``'s next overlapped pass; 0 = the full
+        program.  The smallest rung of ``engine.queue_ladder`` with
+        ``workqueue.RUNG_MARGIN`` of room over the last resolved need
+        (host bookkeeping: no device round trip), the top where no need
+        is known, full where the need exceeds the top or the last
+        snapshot did not fit the top queues.  After an overflow the need
+        is the overflowed snapshot's own; if the re-dispatch overflows
+        too, the full program covers it, so one burst costs at most two
+        passes more than its cover."""
+        ladder = g.engine.queue_ladder
+        if not ladder or not g.predicted_fits:
+            return 0
+        if overflowed and g.redispatch:
+            return 0
+        if g.last_need is None or retry:
+            return 0 if overflowed else ladder[-1]
+        return workqueue.pick_rung(ladder, g.last_need)
 
     def _swap_fn(self, label: str):
         """One-dispatch epoch swap for the live view: per leaf, the epoch-A
@@ -1131,8 +1186,8 @@ class ProtectedStore:
         ``_resolve`` never pays a synchronous device round trip.
         """
         labels = tuple(g.label for g, *_ in items)
-        variants = tuple("async_queued" if q else "async_full"
-                         for _, q, *_ in items)
+        variants = tuple(_queued_variant(g.rung) if q else "async_full"
+                         for g, q, *_ in items)
         lv = get_leaves()
         subs = tuple({n: lv[n] for n in g.names} for g, *_ in items)
         red_subs = tuple({n: out[n] for n in g.names} for g, *_ in items)
@@ -1147,7 +1202,7 @@ class ProtectedStore:
         # thread was measured and rejected: a donating caller (train step,
         # decode step) deletes the captured buffers before the thread gets
         # to shard them.
-        outs, fits, stripes = self._update_many_fn(labels, variants)(
+        outs, fits, counts = self._update_many_fn(labels, variants)(
             subs, red_subs)
         ev = threading.Event() if self.policy.dispatcher_thread else None
         pendings = []
@@ -1159,7 +1214,7 @@ class ProtectedStore:
             # wedged device counts as wedged from the moment the
             # foreground handed it off.
             p = _Pending(red=outs[i], fits=fits, queued=queued, step=step,
-                         launched=ev, fits_index=i, stripes=stripes,
+                         launched=ev, fits_index=i, counts=counts,
                          prev_step=prev_step, prev_time=prev_time)
             g.pending = p
             pendings.append(p)
@@ -1169,11 +1224,11 @@ class ProtectedStore:
             # execution (the fetch blocks *it*, not the tick) and
             # publishes the folded per-group fit bits and stripe counts;
             # ``_resolve`` then only reads plain Python values.
-            def resolve_job(fits=fits, stripes=stripes, pendings=pendings,
+            def resolve_job(fits=fits, counts=counts, pendings=pendings,
                             ev=ev):
                 try:
                     with trace.span("resolver.fetch"):
-                        host = jax.device_get((fits, stripes))
+                        host = jax.device_get((fits, counts))
                     _publish(pendings, *map(np.asarray, host))
                 except BaseException as e:   # surfaces at resolution
                     for p in pendings:
@@ -1186,12 +1241,12 @@ class ProtectedStore:
             # Inline mode (PR3..PR8 behavior): start the non-blocking
             # device->host copies now; resolution folds the landed rows.
             fits.copy_to_host_async()
-            stripes.copy_to_host_async()
+            counts.copy_to_host_async()
         else:
             # Backend without a non-blocking device->host copy: fetch
             # HERE, at dispatch time — the resolve-side read must stay a
             # host memory read, never a synchronous round trip.
-            _publish(pendings, np.asarray(fits), np.asarray(stripes))
+            _publish(pendings, np.asarray(fits), np.asarray(counts))
         view: Dict[str, LeafRedundancy] = {}
         for (g, *_), (snaps, fresh), rs in zip(items, swaps, red_subs):
             view.update({n: dataclasses.replace(
@@ -1235,9 +1290,16 @@ class ProtectedStore:
                 raise p.error
             fits = _fits_host_pending(p)
             overflowed = p.queued and not fits
-            self._count_pass(g, p.queued, None if overflowed
-                             else _stripes_host_pending(p))
-        g.predicted_fits = fits
+            stripes, need = _counts_host_pending(p)
+            self._count_pass(g, p.queued, None if overflowed else stripes,
+                             g.rung)
+        # A pass that overflowed a rung below the top says nothing of the
+        # top: the re-dispatch sizes its rung from the need instead.
+        if not overflowed or g.rung == g.engine.queue_ladder[-1]:
+            g.predicted_fits = fits
+        # The need of a snapshot that due ticks coalesced into understates
+        # the next one: it is unknown.
+        g.last_need = None if p.coalesced else need
         out = {n: dataclasses.replace(p.red[n], dirty=red_sub[n].dirty)
                for n in g.names}
         g.pending = None
@@ -1340,12 +1402,13 @@ class ProtectedStore:
         their own red — the donating blocking program would invalidate it.
         Bitwise-identical to the blocking full program (queued=False never
         overflows, so its dirty/shadow outputs are zeros too)."""
-        repaired, fits, stripes = self._update_fn(g.label, "async_full")(
+        repaired, fits, counts = self._update_fn(g.label, "async_full")(
             {n: leaves[n] for n in g.names}, {n: out[n] for n in g.names})
         with trace.waited(self.counters, "fallback"):
-            fits, stripes = jax.device_get((fits, stripes))
+            fits, counts = jax.device_get((fits, counts))
         g.predicted_fits = _fits_host(fits)
-        self._count_pass(g, False, int(np.sum(stripes)))
+        stripes, g.last_need = _counts_host(counts)
+        self._count_pass(g, False, stripes)
         out.update(repaired)
 
     def _scrub_fn(self, label: str):
@@ -1523,9 +1586,10 @@ class ProtectedStore:
                                 # bump below: the governor's wedged-dispatch
                                 # abandon rolls back to these, and the batched
                                 # dispatch only runs after this loop.
+                                g.rung = self._next_rung(g, ovf, retry)
+                                g.redispatch = bool(ovf)
                                 to_dispatch.append(
-                                    (g, bool(not ovf and g.engine.has_queue
-                                             and g.predicted_fits),
+                                    (g, g.rung > 0,
                                      g.last_update_step, g.last_update_time))
                                 g.last_update_step = step
                                 g.last_update_time = now

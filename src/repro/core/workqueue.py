@@ -17,15 +17,25 @@ CPU/XLA reference path — pays for dirty stripes, not region size:
    deltas (XOR algebra makes this bitwise-exact) instead of rehashing every
    checksum.
 
+**The capacity is a ladder, not one K.**  Padding rows still cost a
+gather, a reduce and a scatter each, so a pass pays for ``K`` rows whatever
+the dirty count.  :func:`queue_ladder` derives a short ladder of static
+capacities from a queue's top capacity (:func:`queue_capacity`): the top
+divided by :data:`RUNG_STEP` down to :data:`MIN_RUNG_STRIPES`.  Each rung
+is its own compiled program; the store picks one per pass with
+:func:`pick_rung` from the last pass's need (its largest leaf's
+dirty-stripe count, fetched with the fit signal), with
+:data:`RUNG_MARGIN` of room.
+
 **Overflow is a host-side dispatch decision, not a device branch.**  A
 ``lax.cond``/``fori_loop`` realization was measured first and rejected:
 XLA materializes every conditional operand (the whole lane view, parity,
 checksums), which costs more than the full recompute it was meant to skip.
 Instead :func:`queued_update` assumes the caller has already checked
 ``dirty-stripe count <= capacity`` (see ``RedundancyEngine.queue_fits``);
-the store's tick — a host loop by construction — dispatches either the
-queued or the full jitted program.  Both produce bitwise-identical results
-on their shared domain, so the fallback never changes semantics.
+the store's tick — a host loop by construction — dispatches either a
+queued rung or the full jitted program.  All produce bitwise-identical
+results on their shared domain, so the fallback never changes semantics.
 
 Everything here is shard-oblivious on purpose: under a mesh the engine
 calls these helpers *inside* ``shard_map``, so each shard compacts its own
@@ -47,6 +57,20 @@ from . import checksum
 
 DEFAULT_QUEUE_FRAC = 0.125   # queue capacity as a fraction of n_stripes
 MIN_QUEUE_STRIPES = 4
+# The ladder's bottom: below it a pass is bound by what does not scale
+# with K (the parity array's copy, the stripe-id compaction), not by the
+# queue's rows.
+MIN_RUNG_STRIPES = 256
+# Ratio of neighbouring rungs.  Every rung is one more program to trace
+# and load at attach: on one TPU v5e, halving the top instead (7 rungs on
+# a 2 GiB heap, 4 here) put 16% on a read-only YCSB cell's set-up with a
+# warm compile cache, where 4 puts 6%.
+RUNG_STEP = 4
+# Room a rung keeps over the last count: the next snapshot may hold more.
+RUNG_MARGIN = 1.25
+# A snapshot's need where a leaf's dirty stripes exceed its own top
+# capacity: past every rung, so only the full program holds it.
+NO_RUNG = 2**31 - 1
 
 
 def queue_capacity(n_stripes: int, frac: float,
@@ -63,6 +87,33 @@ def queue_capacity(n_stripes: int, frac: float,
     if cap >= n_stripes:
         return 0
     return cap
+
+
+def queue_ladder(top: int) -> Tuple[int, ...]:
+    """Compiled capacities of one queue, ascending: ``top`` divided by
+    :data:`RUNG_STEP` while the quotient stays >= :data:`MIN_RUNG_STRIPES`
+    (16384, 4096, 1024, 256).  A top below ``RUNG_STEP * MIN_RUNG_STRIPES``
+    is its own one-rung ladder; ``top`` 0 (no queue) has none.
+    """
+    if top <= 0:
+        return ()
+    rungs = [top]
+    while rungs[-1] // RUNG_STEP >= MIN_RUNG_STRIPES:
+        rungs.append(rungs[-1] // RUNG_STEP)
+    return tuple(reversed(rungs))
+
+
+def pick_rung(ladder: Tuple[int, ...], need: int) -> int:
+    """The smallest rung >= :data:`RUNG_MARGIN` x ``need``; the top rung
+    where only the margin overshoots it; 0 (the full program) where
+    ``need`` itself exceeds the top rung.  ``need`` is the largest queued
+    leaf's (and shard's) dirty-stripe count, or :data:`NO_RUNG` where one
+    exceeds its own top capacity: at rung ``K`` a leaf holds
+    ``min(K, its top)`` stripes."""
+    if not ladder or need > ladder[-1]:
+        return 0
+    want = RUNG_MARGIN * need
+    return next((k for k in ladder if k >= want), ladder[-1])
 
 
 def compact_stripe_ids(

@@ -230,11 +230,17 @@ def test_no_queue_fits_round_trip_on_async_hot_path(monkeypatch):
 
 def test_attach_precompiles_update_variants():
     """Satellite: attach warms both Algorithm-1 variants (plus the epoch
-    swap) so the first due tick never hides a compile stall."""
+    swap) so the first due tick never hides a compile stall: the tick's
+    batched program at the full variant and at every rung of the queued
+    one, and settle's single-group full fallback."""
     store = _store(True)
-    label = _group(store).label
+    g = _group(store)
+    label = g.label
     assert (label, "async_full") in store._jit_update
-    assert (label, "async_queued") in store._jit_update
+    assert ((label,), ("async_full",)) in store._jit_update
+    assert g.engine.queue_ladder
+    for k in g.engine.queue_ladder:
+        assert ((label,), (f"async_queued@{k}",)) in store._jit_update
     assert (label, "swap") in store._jit_misc
     blocking = _store(False)
     label = _group(blocking).label

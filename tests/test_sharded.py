@@ -50,18 +50,22 @@ def test_sharded_queued_and_async_programs_are_collective_free():
             (eng.has_queue, eng.queue_capacity("w"), eng.queue_capacity("e"))
         lv = put(make_leaves())
         red = store.init(lv)
-        for variant in ("queued", "full", "async_queued", "async_full"):
+        from repro.core.store import _queued_variant
+        for variant in ("queued", "full", "async_full"):
             lowered = store._build_update(g.label, variant).lower(lv, red)
             assert_no_collectives(lowered, variant)
-        for variant in ("async_queued", "async_full"):
+        rungs = tuple(map(_queued_variant, eng.queue_ladder))
+        assert rungs, eng.queue_ladder
+        for variant in rungs + ("async_full",):
             lowered = store._build_update_many(
                 (g.label,), (variant,)).lower((lv,), (red,))
             assert_no_collectives(lowered, "many_" + variant)
         outs, stacked, counts = store._update_many_fn(
-            (g.label,), ("async_queued",))((lv,), (red,))
-        # one row per group, one flag column per device
+            (g.label,), (rungs[-1],))((lv,), (red,))
+        # one row per group, one flag column per device; each device's
+        # [stripes, need] pair beside its flag
         assert stacked.shape == (1, 8), stacked.shape
-        assert counts.shape == (1, 8), counts.shape
+        assert counts.shape == (1, 8, 2), counts.shape
         assert workqueue.fold_fits_host(np.asarray(stacked)[0])
         print("PROGRAMS_OK")
     """, "PROGRAMS_OK", prelude=MESH_PRELUDE)
@@ -319,3 +323,47 @@ def test_sharded_training_matches_single_device():
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-5)
         print("MATCH_OK", l1, l8)
     """, "MATCH_OK")
+
+
+def test_sharded_rung_follows_the_largest_shard():
+    """Per-shard queues pick their rung from the largest shard's count,
+    not the sum: 150 dirty stripes on each of 8 shards (1200 in all, past
+    the 1024 top) run at 256 a shard, paying 8 x 256 queue rows a pass,
+    and settle to the full recompute's bits."""
+    run_snippet("""
+        from repro.core import RedundancyEngine
+        mesh = MESH
+        spec = {"h": P(("pod", "data", "model"), None)}
+        shard = NamedSharding(mesh, spec["h"])
+        rows = 8 * 8192                   # 2048 stripes a shard: top 1024
+        rng = np.random.default_rng(0)
+        lv = {"h": jax.device_put(jnp.asarray(rng.integers(
+            0, 2**32, (rows, 128), dtype=np.uint32)), shard)}
+        pol = RedundancyPolicy.single(
+            "vilamb", period_steps=1, lanes_per_block=128,
+            work_queue_frac=0.5, async_tick=True, precompile=False)
+        store = ProtectedStore(pol, mesh=mesh).attach(lv, specs=spec)
+        g = next(iter(store.groups.values()))
+        assert g.engine.queue_ladder == (256, 1024), g.engine.queue_ladder
+        red = store.init(lv)
+        for step in range(1, 5):
+            sids = np.concatenate([s * 2048 + rng.choice(2048, 150, replace=False)
+                                   for s in range(8)])
+            idx = jnp.asarray(np.sort(sids * 4))
+            lv = {"h": lv["h"].at[idx].add(jnp.uint32(step))}
+            red = store.on_write(red, events={"h": jnp.zeros((rows,), bool)
+                                              .at[idx].set(True)})
+            store.sync_inflight()
+            before = dict(store.counters)
+            red, rep = store.tick(lv, red, step)
+            assert not rep.overflowed
+            if step >= 2:
+                assert g.rung == 256 and g.pending.queued, g.rung
+            if step >= 3:
+                grew = store.counters["update.queue_rows"] - before["update.queue_rows"]
+                assert grew == 8 * 256, grew
+                assert store.counters["update.stripes"] - before["update.stripes"] == 1200
+        red = store.settle(red, lv)
+        assert_red_equal(red, g.engine.init(lv))
+        print("RUNG_OK")
+    """, "RUNG_OK", prelude=MESH_PRELUDE)

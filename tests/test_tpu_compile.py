@@ -12,6 +12,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import ProtectedStore, RedundancyPolicy
+from repro.core.store import _queued_variant
 from repro.kernels.checksum import ops as cops
 from repro.kernels.parity import ops as pops
 from repro.kernels.redundancy import ops as rops
@@ -71,8 +72,38 @@ def test_region_queued_update_fits_one_chip(one_chip):
     (group,) = store._protected()
     put = lambda t: jax.tree.map(
         lambda s: _struct(one_chip, s.shape, s.dtype), t)
-    compiled = store._build_update(group.label, "async_queued").lower(
-        put({"heap": heap}), put(store.red_structs())).compile()
+    top = _queued_variant(group.engine.queue_ladder[-1])
+    compiled = store._build_update_many((group.label,), (top,)).lower(
+        (put({"heap": heap}),), (put(store.red_structs()),)).compile()
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, used
+
+
+def test_overlap_program_compiles_for_every_rung(one_chip):
+    """Every rung of the 2 GiB region's queue ladder compiles into the
+    tick's batched overlap program and fits one chip, and the bottom rung
+    moves under a quarter of the top rung's bytes: padding rows cost a
+    gather, a reduce and a scatter each, so a pass pays for its K.  The
+    heap holds uint32 words, as a key-value region's pages do."""
+    heap = jax.ShapeDtypeStruct((REGION_ROWS, LANES), jnp.uint32)
+    store = ProtectedStore(RedundancyPolicy.single(
+        "vilamb", lanes_per_block=LANES, stripe_data_blocks=4,
+        precompile=False)).attach({"heap": heap})
+    (group,) = store._protected()
+    ladder = group.engine.queue_ladder
+    assert ladder == (256, 1024, 4096, 16384), ladder
+    put = lambda t: jax.tree.map(
+        lambda s: _struct(one_chip, s.shape, s.dtype), t)
+    moved = {}
+    for k in ladder:
+        compiled = store._build_update_many(
+            (group.label,), (_queued_variant(k),)).lower(
+            (put({"heap": heap}),), (put(store.red_structs()),)).compile()
+        mem = compiled.memory_analysis()
+        used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert used < HBM_BYTES, (k, used)
+        cost = compiled.cost_analysis()
+        moved[k] = (cost[0] if isinstance(cost, list) else cost)[
+            "bytes accessed"]
+    assert moved[ladder[0]] < moved[ladder[-1]] / 4, moved

@@ -354,3 +354,271 @@ def test_queued_preserves_scrub_detection():
     corrupted = B.from_lanes(lanes.at[20, 3].add(99), meta)
     mm = eng.scrub(dict(leaves2, w=corrupted), red)
     assert np.flatnonzero(np.asarray(mm["w"])).tolist() == [20]
+
+
+# ------------------------------------------------------- the rung ladder
+TOP_2G = (256, 1024, 4096, 16384)
+
+
+@pytest.mark.parametrize("n_stripes, frac, ladder", [
+    (131072, 0.125, TOP_2G),          # a 2 GiB heap of 4 KiB pages, 4+1
+    (65536, 0.125, (512, 2048, 8192)),  # one shard of four over 4 GiB
+    (20000, 0.25, (312, 1250, 5000)),
+    (8000, 0.125, (1000,)),           # 250 would fall under the floor
+    (10, 0.5, (5,)),                  # a top under 256 is its own rung
+    (1, 0.5, ()),                     # no queue, no ladder
+])
+def test_queue_ladder_derivation(n_stripes, frac, ladder):
+    assert workqueue.queue_ladder(
+        workqueue.queue_capacity(n_stripes, frac)) == ladder
+
+
+@pytest.mark.parametrize("stripes, rung", [
+    (0, 256), (204, 256), (205, 1024), (819, 1024), (931, 4096),
+    (13107, 16384), (13108, 16384), (16384, 16384), (16385, 0)])
+def test_pick_rung(stripes, rung):
+    """Smallest rung with 1.25x room; the top where only the room
+    overshoots it; the full program (0) past the top."""
+    assert workqueue.pick_rung(TOP_2G, stripes) == rung
+
+
+BIG_ROWS = 32766                 # 512 B blocks: 8192 stripes, the last of 2
+BIG_STRIPES = 8192
+BIG_RUNGS = (256, 1024, 4096)    # frac 0.5: top 4096, divided by 4 to 256
+
+
+def _big_leaves(seed=0):
+    words = np.random.default_rng(seed).integers(
+        0, 2**32, (BIG_ROWS, 128), dtype=np.uint32)
+    return {"h": jnp.asarray(words)}
+
+
+def _big_rows(rng, n, last=False):
+    """Rows (= blocks) dirtying ``n`` distinct stripes, one block each;
+    ``last`` puts the partial last stripe among them."""
+    end = BIG_STRIPES - 1
+    sids = rng.choice(end if last else BIG_STRIPES, size=n - last,
+                      replace=False)
+    if last:
+        sids = np.append(sids, end)
+    off = rng.integers(0, 4, size=len(sids))
+    return np.sort(sids * 4 + np.where(sids == end, off % 2, off))
+
+
+def _row_mask(rows):
+    mask = np.zeros((BIG_ROWS,), bool)
+    mask[rows] = True
+    return jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("rung", BIG_RUNGS)
+def test_every_rung_bitwise_identical_to_full(rung):
+    """The overlap program at every rung equals the full recompute on
+    random masks that fit it (empty, sparse, exactly at the rung with the
+    partial last stripe); one stripe more overflows and keeps the whole
+    snapshot marked."""
+    leaves = _big_leaves()
+    eng = RedundancyEngine(
+        {"h": jax.ShapeDtypeStruct((BIG_ROWS, 128), jnp.uint32)},
+        RedundancyConfig(lanes_per_block=128, stripe_data_blocks=4,
+                         work_queue_frac=0.5))
+    assert eng.queue_ladder == BIG_RUNGS
+    assert eng.queue_rows(rung) == rung
+    red = eng.init(leaves)
+    step = jax.jit(lambda lv, rd: eng.redundancy_step_async(
+        lv, rd, queued=True, rung=rung))
+    rng = np.random.default_rng(rung)
+    for n, last in ((0, False), (rung // 3, False), (rung, True),
+                    (rung + 1, False)):
+        rows = _big_rows(rng, n, last)
+        r = eng.mark_dirty(red, {"h": _row_mask(rows)})
+        lv = {"h": leaves["h"].at[rows].add(jnp.uint32(n + 1))}
+        out, fits, counts = step(lv, r)
+        need = n if n <= BIG_RUNGS[-1] else workqueue.NO_RUNG
+        assert np.asarray(counts).tolist() == [n, need]
+        if n <= rung:
+            assert bool(fits)
+            _assert_red_equal(out, eng.redundancy_step(lv, r))
+        else:
+            assert not bool(fits)
+            np.testing.assert_array_equal(np.asarray(out["h"].shadow),
+                                          np.asarray(r["h"].dirty))
+
+
+def _big_store():
+    pol = RedundancyPolicy.single(
+        "vilamb", period_steps=1, lanes_per_block=128, work_queue_frac=0.5,
+        async_tick=True, precompile=False)
+    return ProtectedStore(pol).attach(_big_leaves())
+
+
+def _big_tick(store, lv, red, step, rows):
+    """Write ``rows``, then a due tick that sees the in-flight pass ready."""
+    lv = {"h": lv["h"].at[rows].add(jnp.uint32(step))}
+    red = store.on_write(red, events={"h": _row_mask(rows)})
+    store.sync_inflight()
+    red, rep = store.tick(lv, red, step)
+    return lv, red, rep
+
+
+def _assert_fresh(store, lv, red):
+    """After ``settle`` every checksum, stripe and meta checksum is the
+    full recompute's, and nothing is left marked."""
+    red = store.settle(red, lv)
+    eng = next(iter(store.groups.values())).engine
+    _assert_red_equal(red, eng.init(lv))
+
+
+def test_tick_steady_state_dispatches_smallest_fitting_rung():
+    """300 dirty stripes a pass: after the pessimistic full first pass,
+    every pass runs at 1024 (the smallest rung >= 375, below the top) and
+    adds exactly 1024 queue rows."""
+    store = _big_store()
+    g = next(iter(store.groups.values()))
+    lv = _big_leaves()
+    red = store.init(lv)
+    rng = np.random.default_rng(1)
+    for step in range(1, 6):
+        before = dict(store.counters)
+        lv, red, rep = _big_tick(store, lv, red, step, _big_rows(rng, 300))
+        assert rep.updated and not rep.overflowed
+        grew = {k: store.counters[k] - before[k] for k in before}
+        if step == 1:
+            assert g.rung == 0 and not g.pending.queued
+        else:
+            assert g.rung == 1024 and g.pending.queued
+        if step >= 3:
+            assert grew["update.queue_rows"] == 1024, grew
+            assert grew["update.passes_queued"] == 1, grew
+            assert grew["update.passes_full"] == 0, grew
+            assert grew["update.stripes"] == 300, grew
+    _assert_fresh(store, lv, red)
+
+
+def test_empty_snapshot_takes_bottom_rung_and_is_bitwise_noop():
+    store = _big_store()
+    g = next(iter(store.groups.values()))
+    lv = _big_leaves()
+    red = store.init(lv)
+    red0 = jax.tree.map(np.asarray, red)
+    red, _ = store.tick(lv, red, 1)              # pessimistic full pass
+    store.sync_inflight()
+    red, rep = store.tick(lv, red, 2)            # its count: 0 stripes
+    assert rep.updated
+    assert g.rung == BIG_RUNGS[0] and g.pending.queued
+    before = store.counters["update.queue_rows"]
+    red = store.settle(red, lv)
+    assert store.counters["update.queue_rows"] - before == BIG_RUNGS[0]
+    _assert_red_equal(red, red0)
+
+
+def test_overflow_redispatches_at_fitting_rung_not_full():
+    """A burst past the rung the last count chose overflows it; the
+    re-dispatch takes the rung that the burst's own count fits (600 ->
+    1024, below the top), not the full program, and freshness holds after
+    settle."""
+    store = _big_store()
+    g = next(iter(store.groups.values()))
+    lv = _big_leaves()
+    red = store.init(lv)
+    rng = np.random.default_rng(2)
+    for step in (1, 2):                          # full, then rung 256
+        lv, red, _ = _big_tick(store, lv, red, step, _big_rows(rng, 100))
+    assert g.rung == 256
+    full = store.counters["update.passes_full"]
+    overflowed = store.counters["update.overflowed"]
+    lv, red, rep = _big_tick(store, lv, red, 3, _big_rows(rng, 600))
+    assert g.rung == 256 and not rep.overflowed  # chosen from 100
+    lv, red, rep = _big_tick(store, lv, red, 4, _big_rows(rng, 100))
+    assert rep.overflowed
+    assert g.rung == 1024 and g.pending.queued
+    lv, red, rep = _big_tick(store, lv, red, 5, _big_rows(rng, 100))
+    assert not rep.overflowed
+    assert store.counters["update.overflowed"] == overflowed + 1
+    assert store.counters["update.passes_full"] == full
+    _assert_fresh(store, lv, red)
+    assert store.counters["update.passes_full"] == full
+
+
+def test_rung_follows_the_largest_leaf_not_the_sum():
+    """A group's rung is sized by its largest leaf's count: two leaves
+    with 2500 dirty stripes each (5000 in all, past the 4096 top, but
+    each within its own 4096 queue) stay queued at the top rung; a
+    third leaf too small for a queue counts in the sum and not in the
+    rung.  No pass falls back to the full program."""
+    pol = RedundancyPolicy.single(
+        "vilamb", period_steps=1, lanes_per_block=128, work_queue_frac=0.5,
+        async_tick=True, precompile=False)
+    lv = {"a": _big_leaves(1)["h"], "b": _big_leaves(2)["h"],
+          "c": jnp.zeros((8, 128), jnp.uint32)}
+    store = ProtectedStore(pol).attach(lv)
+    (g,) = store.groups.values()
+    assert g.engine.queue_ladder == BIG_RUNGS
+    assert [g.engine.queue_capacity(n) for n in "abc"] == [4096, 4096, 0]
+    red = store.init(lv)
+    rng = np.random.default_rng(3)
+    full = None
+    for step in range(1, 5):
+        rows = {"a": _big_rows(rng, 2500), "b": _big_rows(rng, 2500),
+                "c": np.arange(8)}
+        lv = {n: lv[n].at[r].add(jnp.uint32(step)) for n, r in rows.items()}
+        red = store.on_write(red, events={
+            n: jnp.zeros((lv[n].shape[0],), bool).at[r].set(True)
+            for n, r in rows.items()})
+        store.sync_inflight()
+        before = dict(store.counters)
+        red, rep = store.tick(lv, red, step)
+        assert not rep.overflowed
+        grew = {k: store.counters[k] - before[k] for k in before}
+        if step == 1:
+            continue
+        if step == 2:                   # the first pass resolved: full
+            full = store.counters["update.passes_full"]
+        assert g.rung == BIG_RUNGS[-1] and g.pending.queued, g.rung
+        if step >= 3:
+            assert grew["update.queue_rows"] == 2 * BIG_RUNGS[-1], grew
+            assert grew["update.stripes"] == 2 * 2500 + 2, grew
+    red = store.settle(red, lv)
+    assert store.counters["update.passes_full"] == full
+    _assert_red_equal(red, g.engine.init(lv))
+
+
+def test_burst_is_covered_within_the_deadline():
+    """A burst that climbs over several ticks (10, 300, 900, 3000
+    stripes) overflows the rung the last count chose, then the rung its
+    own count fits; the second overflow goes to the full program, not up
+    the ladder again.  No block stays marked for more than
+    max_vulnerable_steps ticks.  Two passes run full: the cover of the
+    burst, and the next one, since the burst's 4200 stripes are past the
+    top queue."""
+    deadline = 2
+    pol = RedundancyPolicy.single(
+        "vilamb", period_steps=1, lanes_per_block=128, work_queue_frac=0.5,
+        max_vulnerable_steps=deadline, async_tick=True, precompile=False)
+    store = ProtectedStore(pol).attach(_big_leaves())
+    (g,) = store.groups.values()
+    lv = _big_leaves()
+    red = store.init(lv)
+    order = iter(np.random.default_rng(4).permutation(BIG_STRIPES - 1))
+    since = np.full((BIG_ROWS,), -1)
+    full = overflowed = None
+    for step, n in enumerate((10, 10, 300, 900, 3000, 10, 10, 10), 1):
+        rows = np.sort([next(order) * 4 for _ in range(n)])
+        since[rows[since[rows] < 0]] = step
+        lv, red, rep = _big_tick(store, lv, red, step, rows)
+        if step == 2:
+            full = store.counters["update.passes_full"]
+            overflowed = store.counters["update.overflowed"]
+        if step in (4, 5):
+            assert rep.overflowed, step
+        if step == 4:
+            assert g.rung == 1024 and g.pending.queued   # fits 300
+        if step == 5:
+            assert g.rung == 0 and not g.pending.queued  # not 4096
+        marked = np.asarray(store.vulnerable_masks(red)["h"])
+        since[~marked] = -1
+        age = step - since[marked]
+        assert age.size == 0 or age.max() <= deadline, (step, age.max())
+    assert store.counters["update.overflowed"] == overflowed + 2
+    assert store.counters["update.passes_full"] == full + 2
+    _assert_fresh(store, lv, red)
